@@ -160,6 +160,8 @@ def main() -> None:
                          "failure)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     if args.check:
         # suites consult benchmarks._check.check_mode(); set before import
         import os

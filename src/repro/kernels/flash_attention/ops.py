@@ -8,14 +8,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ..backend import interpret_mode
 from .flash_attention import flash_attention_pallas
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # pragma: no cover
-        return False
 
 
 @functools.partial(
@@ -26,7 +20,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int | None = None,
                     softmax_scale: float | None = None, block_q: int = 512,
                     block_k: int = 512, interpret: bool | None = None):
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = interpret_mode()
     B, Sq, Hq, D = q.shape
     Skv = k.shape[1]
     bq = min(block_q, max(8, Sq))

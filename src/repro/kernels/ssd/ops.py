@@ -8,14 +8,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ..backend import interpret_mode
 from .ssd import ssd_pallas
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # pragma: no cover
-        return False
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
@@ -28,7 +22,7 @@ def ssd(x, dt, A, B, C, D, *, chunk: int = 128, interpret: bool | None = None):
     output; serving paths that need the state use models/ssm directly.
     """
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = interpret_mode()
     b, S, H, P = x.shape
     G = B.shape[2]
     rep = H // G

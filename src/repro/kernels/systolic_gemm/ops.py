@@ -1,5 +1,6 @@
 """jit'd public wrappers for the systolic GEMM kernels: pad to block
-multiples, dispatch to Pallas (interpret=True on CPU), slice back.
+multiples, dispatch to Pallas (Mosaic on TPU, interpret mode on the CPU
+backend: kernels/backend.py), slice back.
 
 Block geometry defaults to the DSE autotuner
 (parallel.autoshard.choose_blocks — tile_stats-driven, VMEM-budget-aware,
@@ -20,15 +21,9 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ..backend import interpret_mode
 from .systolic_gemm import (grouped_systolic_gemm_pallas,
                             systolic_gemm_nt_pallas, systolic_gemm_pallas)
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # pragma: no cover
-        return False
 
 
 def _pad_to(x, m, axis):
@@ -76,7 +71,7 @@ def systolic_gemm(x, w, scale=None, bias=None, *, activation=None,
     Blocks default to the tile_stats autotuner (choose_blocks).
     """
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = interpret_mode()
     M, K = x.shape
     N = w.shape[1]
     if block_m is None or block_n is None or block_k is None:
@@ -148,7 +143,7 @@ def systolic_gemm_t(x, w, scale=None, bias=None, *, activation=None,
     token table as the LM head directly. Same autotune/padding contract as
     `systolic_gemm` (the cost model is layout-invariant)."""
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = interpret_mode()
     M, K = x.shape
     N = w.shape[0]
     if block_m is None or block_n is None or block_k is None:
@@ -211,7 +206,7 @@ def grouped_gemm(x, w, scale=None, bias=None, *, activation=None,
     Same padding/autotune contract as `systolic_gemm` (blocks are chosen
     for the per-group (M, K, N) problem)."""
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = interpret_mode()
     G, M, K = x.shape
     N = w.shape[2]
     if block_m is None or block_n is None or block_k is None:

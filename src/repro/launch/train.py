@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_arch, reduced as reduce_cfg
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.model import Model
 from repro.train.checkpoint import (latest_step, prune_checkpoints,
                                     restore_checkpoint, save_checkpoint)
@@ -45,6 +46,7 @@ def main():
                     help="simulate a host failure after N steps")
     args = ap.parse_args()
 
+    use_compile_cache()
     cfg = get_arch(args.arch)
     if args.reduced:
         cfg = reduce_cfg(cfg)

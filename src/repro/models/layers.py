@@ -19,6 +19,7 @@ configs on the CPU dry-run host.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any
 
@@ -38,7 +39,13 @@ class ParamSpec:
         assert len(self.shape) == len(self.axes), (self.shape, self.axes)
 
 
+@functools.partial(jax.jit, static_argnums=1)
 def _init_leaf(key, spec: ParamSpec):
+    """One leaf, as one program: the float32 draw, then the scale fused
+    with the cast, so only the draw and the leaf's own dtype are ever on
+    the device (an eager `(draw * scale).astype` also holds the float32
+    product). The barrier keeps the draw the same program as an eager
+    `jax.random.normal`: fusing the scale into it changes float32 bits."""
     if spec.init == "zeros":
         return jnp.zeros(spec.shape, spec.dtype)
     if spec.init == "ones":
@@ -47,7 +54,9 @@ def _init_leaf(key, spec: ParamSpec):
     if scale is None:
         fan_in = spec.shape[-2] if len(spec.shape) >= 2 else spec.shape[-1]
         scale = 1.0 / math.sqrt(max(1, fan_in))
-    return (jax.random.normal(key, spec.shape, jnp.float32) * scale).astype(spec.dtype)
+    draw = jax.lax.optimization_barrier(
+        jax.random.normal(key, spec.shape, jnp.float32))
+    return (draw * scale).astype(spec.dtype)
 
 
 def is_spec(x) -> bool:
@@ -55,9 +64,16 @@ def is_spec(x) -> bool:
 
 
 def init_from_schema(rng, schema):
+    """Random params: leaf i draws from the i-th split of `rng` in
+    flattened order. Leaves are made largest first, while the device is
+    emptiest, which changes no value: a full-width stack of [L, d, d_ff]
+    leaves would not find room for its float32 draw last."""
     leaves, treedef = jax.tree_util.tree_flatten(schema, is_leaf=is_spec)
     keys = jax.random.split(rng, len(leaves))
-    vals = [_init_leaf(k, s) for k, s in zip(keys, leaves)]
+    vals = [None] * len(leaves)
+    for i in sorted(range(len(leaves)),
+                    key=lambda i: -math.prod(leaves[i].shape)):
+        vals[i] = _init_leaf(keys[i], leaves[i])
     return jax.tree_util.tree_unflatten(treedef, vals)
 
 

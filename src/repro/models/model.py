@@ -79,8 +79,8 @@ class Model:
         self.constrain = constrain or (lambda x, kind: x)
         # use_pallas routes dense/GQA projections + MLPs through the
         # systolic pod GEMM kernel with DSE-autotuned block geometry
-        # (kernels/systolic_gemm; interpret mode off-TPU). Reference
-        # einsum path stays the default and the numerics oracle.
+        # (kernels/systolic_gemm; interpret mode on the CPU backend).
+        # Reference einsum path stays the default and the numerics oracle.
         self.use_pallas = use_pallas
         # unroll=True replaces lax.scan with a Python loop over indexed
         # layer params — used by the dry-run's L1/L2 flop-calibration

@@ -26,14 +26,6 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
-
-
-def _axis_size(axis_name: str) -> int:
-    # jax.lax.axis_size only exists in newer jax; psum(1) works everywhere.
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return int(jax.lax.psum(1, axis_name))
 
 
 def butterfly_all_reduce(x: jax.Array, axis_name: str) -> jax.Array:
@@ -42,7 +34,7 @@ def butterfly_all_reduce(x: jax.Array, axis_name: str) -> jax.Array:
     Round t exchanges with the partner differing in bit t of the axis
     index; after all rounds every shard holds the full sum.
     """
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     assert n & (n - 1) == 0, "butterfly all-reduce needs power-of-two axis"
     rounds = int(math.log2(n))
     idx = jax.lax.axis_index(axis_name)
@@ -59,7 +51,7 @@ def butterfly_all_reduce_expansion2(x: jax.Array, axis_name: str) -> jax.Array:
     plane-0 (LSB-first) and plane-1 (MSB-first) schedules — disjoint link
     sets per round, doubling effective injection bandwidth (the paper's
     expansion argument)."""
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     assert n & (n - 1) == 0
     rounds = int(math.log2(n))
     flat = x.reshape(-1)
@@ -84,7 +76,7 @@ def butterfly_reduce_scatter(x: jax.Array, axis_name: str) -> jax.Array:
     """Recursive-halving reduce-scatter: log2(N) rounds, halving payload
     each round; shard i ends with the i-th 1/N slice of the sum.
     x's leading dim must be divisible by N."""
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     assert n & (n - 1) == 0
     rounds = int(math.log2(n))
     idx = jax.lax.axis_index(axis_name)
@@ -111,7 +103,7 @@ def butterfly_all_gather(x: jax.Array, axis_name: str) -> jax.Array:
     `butterfly_reduce_scatter` (same bit walk) the composition
     all_gather(reduce_scatter(x)) == all_reduce(x) holds exactly, which is
     the only way we use it (ZeRO-1 gradient path)."""
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     assert n & (n - 1) == 0
     rounds = int(math.log2(n))
     idx = jax.lax.axis_index(axis_name)
@@ -130,7 +122,7 @@ def butterfly_all_gather(x: jax.Array, axis_name: str) -> jax.Array:
 def ring_all_reduce(x: jax.Array, axis_name: str) -> jax.Array:
     """Baseline: 2(N-1)-step ring (reduce-scatter + all-gather), the
     torus-native schedule XLA favors — SOSA's mesh/H-tree analogue."""
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     flat = x.reshape(-1)
     pad = (-flat.shape[0]) % n
     if pad:
@@ -177,8 +169,8 @@ def all_reduce_under_mesh(mesh: Mesh, axis_name: str, impl: str = "butterfly"):
     spec_in = P(axis_name)
     spec_out = P(axis_name)
 
-    @functools.partial(shard_map, mesh=mesh, in_specs=spec_in,
-                       out_specs=spec_out, check_rep=False)
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=spec_in,
+                       out_specs=spec_out, check_vma=False)
     def _run(x):
         return fn(x, axis_name)
 

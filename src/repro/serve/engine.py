@@ -440,19 +440,22 @@ class ServeEngine:
 
     # -- bucketed prefill ------------------------------------------------
     def _probe_batch_axes(self):
-        """Per-leaf batch axis of the cache pytree, found by diffing a
-        2-lane cache against a 1-lane cache (static metadata; makes lane
-        insertion exact instead of shape-guessed). Probed from throwaway
-        trees, never self.cache: the batch axis doesn't depend on the
-        engine's slot count, and a slots==1 engine has no size difference
-        of its own to diff (assuming axis 0 there scattered stacked-layer
+        """Per-leaf batch axis of the cache pytree, found by diffing the
+        shapes of a 2-lane cache against a 1-lane cache (static metadata;
+        makes lane insertion exact instead of shape-guessed). Probed from
+        abstract trees (jax.eval_shape: nothing lands on the device),
+        never self.cache: the batch axis doesn't depend on the engine's
+        slot count, and a slots==1 engine has no size difference of its
+        own to diff (assuming axis 0 there scattered stacked-layer
         leaves — length [L, B], k [L, B, T, H, D] — along the LAYER axis,
         silently zeroing every layer past the first)."""
         # always probed from DENSE trees: the paged prefill runs its
         # forward over a dense transient lane cache, so the axes tree must
         # mirror that structure (the pool-shaped leaves never need axes)
-        big = self.model.init_cache(2, self.max_len, src_len=self.src_len)
-        ref1 = self.model.init_cache(1, self.max_len, src_len=self.src_len)
+        def shapes(batch):
+            return jax.eval_shape(lambda: self.model.init_cache(
+                batch, self.max_len, src_len=self.src_len))
+        big, ref1 = shapes(2), shapes(1)
 
         def axis(b, small):
             for ax in range(b.ndim):

@@ -27,7 +27,6 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from ..parallel.collectives import (butterfly_all_reduce,
                                     butterfly_all_reduce_expansion2)
@@ -96,10 +95,10 @@ def make_grad_sync(mesh: Mesh, axis: str = "pod", impl: str = "psum"):
         spec = P(None)
 
         @functools.partial(
-            shard_map, mesh=mesh,
+            jax.shard_map, mesh=mesh,
             in_specs=(spec, spec if error is not None else P()),
             out_specs=(spec, spec if error is not None else P()),
-            check_rep=False)
+            check_vma=False)
         def run(x, e):
             r, ne = red(x, e if error is not None else None)
             return r, (ne if ne is not None else jnp.zeros((), x.dtype))

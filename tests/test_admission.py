@@ -574,8 +574,10 @@ def _bench_run_module():
     return mod
 
 
-def test_check_exits_nonzero_on_error_rows(monkeypatch, capsys):
+def test_check_exits_nonzero_on_error_rows(monkeypatch, capsys, tmp_path):
     run = _bench_run_module()
+    # an env-named cache dir keeps run.main() off this process's jax config
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
 
     def boom():
         raise RuntimeError("suite blew up")
@@ -591,8 +593,9 @@ def test_check_exits_nonzero_on_error_rows(monkeypatch, capsys):
     assert "CHECK FAIL" in out.err
 
 
-def test_check_passes_on_clean_suite(monkeypatch, capsys):
+def test_check_passes_on_clean_suite(monkeypatch, capsys, tmp_path):
     run = _bench_run_module()
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     monkeypatch.setattr(run, "load_suites",
                         lambda: {"tiny": lambda: ["tiny/x,1,ok=1"]})
     monkeypatch.setattr("sys.argv", ["run.py", "--check"])
