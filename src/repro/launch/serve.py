@@ -12,7 +12,12 @@ non-finite logits.
 
 `--metrics` prints the engine's telemetry snapshot (obs.metrics) after the
 run; `--trace-out PATH` writes the run as Chrome trace-event JSON —
-drag-and-drop it into ui.perfetto.dev or chrome://tracing.
+drag-and-drop it into ui.perfetto.dev or chrome://tracing. It holds the
+engine's span tree: each step, its admission, the prefill and decode calls
+with their dispatch and host sync, and the host work between them. While
+that tracer is attached the engine also opens a jax.profiler annotation
+per span, so a jax.profiler capture shows the same `engine.*` spans on its
+host plane, on the device trace's clock.
 
 Overload & failure knobs (serve/admission.py, serve/chaos.py):
 `--policy {fifo,edf,slo-aware}` selects the admission policy, `--deadline
@@ -83,7 +88,9 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--metrics", action="store_true",
                     help="print the obs.metrics snapshot after the run")
     ap.add_argument("--trace-out", type=str, default=None, metavar="PATH",
-                    help="write the run as Perfetto/Chrome trace JSON")
+                    help="write the run as Perfetto/Chrome trace JSON: "
+                    "the engine's span tree of every step (also shown as "
+                    "engine.* spans by a jax.profiler capture)")
     ap.add_argument("--policy", choices=POLICIES, default="fifo",
                     help="admission policy (serve/admission.py)")
     ap.add_argument("--deadline", type=float, default=None, metavar="S",

@@ -1,19 +1,23 @@
 """Chrome trace-event / Perfetto JSON export of a serving timeline.
 
-`ServeEngine` (serve/engine.py) emits one `Span` per device call — a
-prefill launch or a fused decode chunk — into the duck-typed tracer
-(`tenancy.ServeTraceRecorder.on_span`). `to_chrome_trace` lowers the
-recorded spans to the Chrome trace-event JSON format (the `traceEvents`
-array of "X" complete events), which both `chrome://tracing` and Perfetto
-(ui.perfetto.dev) open directly, so an engine run can be inspected on a
-real timeline: bucketed prefill launches, decode chunk cadence, lane
-occupancy and emitted-token counts per chunk in the event args.
+`ServeEngine` (serve/engine.py) emits the span tree of every step into
+the duck-typed tracer (`tenancy.ServeTraceRecorder.on_span`): the step,
+its admission, each device call — a prefill launch or a fused decode
+chunk — with its dispatch and host sync, and the host work between them;
+every span's args carry its `id` and its `parent` id. `to_chrome_trace`
+lowers the recorded spans to the Chrome trace-event JSON format (the
+`traceEvents` array of "X" complete events), which both `chrome://tracing`
+and Perfetto (ui.perfetto.dev) open directly, so an engine run can be
+inspected on a real timeline: bucketed prefill launches, decode chunk
+cadence, the host time around them, lane occupancy and emitted-token
+counts per chunk in the event args.
 
 Spans carry host wall-clock (perf_counter) timestamps relative to the
 engine's construction; timestamps are re-based to the earliest span so
 traces start at t=0. Each span category ("prefill", "decode", ...) gets
-its own track (tid) — the engine is single-threaded and step-locked, so
-tracks encode phase, not concurrency.
+its own track (tid): "prefill" and "decode" hold the device calls,
+"engine" the rest of the tree — the engine is single-threaded and
+step-locked, so tracks encode phase, not concurrency.
 """
 
 from __future__ import annotations
@@ -25,12 +29,13 @@ from typing import Iterable
 
 @dataclasses.dataclass(frozen=True)
 class Span:
-    """One timed engine phase: a device call the host waited on."""
+    """One timed engine phase: a step, a device call the host waited on,
+    or host work between calls."""
 
     name: str
     ts: float                  # start, seconds (engine-relative wall clock)
     dur: float                 # duration, seconds
-    cat: str = "serve"         # track: "prefill" | "decode" | ...
+    cat: str = "serve"         # track: "prefill" | "decode" | "engine"
     args: dict = dataclasses.field(default_factory=dict)
 
     @property

@@ -37,7 +37,10 @@ pod GEMM, kernels/systolic_gemm). Pass
 `tracer=tenancy.ServeTraceRecorder()` to record the engine's actual
 prefill/decode timeline; events are emitted in the same step-locked order
 as the seed engine (decode events are reconstructed per scan step from the
-chunk's emit masks), so `tenancy/trace.py` lowers them unchanged.
+chunk's emit masks), so `tenancy/trace.py` lowers them unchanged. The same
+tracer gets each step's span tree (step > admit, prefill and decode calls
+with their dispatch and sync, host work between them), which also lands on
+the host plane of any jax.profiler capture as `engine.*` annotations.
 
 Overload & failure semantics (serve/admission.py, serve/chaos.py): every
 submitted request reaches exactly one terminal state — ``done`` |
@@ -58,6 +61,7 @@ tests/test_serving.py and tests/test_admission.py).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
 import time
@@ -79,6 +83,8 @@ from .chaos import (FaultInjector, NumericalFault, PermanentFault,
                     SilentCorruption, SlowChunkDetector,
                     TransientDeviceError, check_lanes_finite)
 from .paging import PagePool
+
+_OFF = contextlib.nullcontext()       # a span while no tracer takes spans
 
 
 @dataclasses.dataclass
@@ -116,6 +122,81 @@ class Request:
         return self.state in ("done", "rejected", "expired")
 
 
+class _OpenSpan:
+    """A span of the engine's tree while it is open."""
+
+    __slots__ = ("name", "cat", "id", "parent", "timed", "t_start", "t_end",
+                 "args", "held")
+
+    def __init__(self, name: str, cat: str, sid: int,
+                 parent: Optional[int], timed: bool):
+        self.name, self.cat, self.id, self.parent = name, cat, sid, parent
+        self.timed = timed
+        self.t_start = self.t_end = None
+        self.args: dict = {}
+        self.held: list[_OpenSpan] = []    # closed children, untimed only
+
+    def done(self, t_start: float, t_end: float, **args) -> None:
+        """Bounds and args of an untimed span (a device call): set only
+        when the call succeeded, so a failed call leaves no span."""
+        self.t_start, self.t_end = t_start, t_end
+        self.args.update(args)
+
+
+class _SpanTree:
+    """The engine's spans while a tracer that takes spans (`on_span`) is
+    attached. Each span gets an integer `id` and the `parent` id of the
+    span open around it (None for a `step`), both passed to `on_span`
+    with its args, and opens a jax.profiler.TraceAnnotation named
+    `engine.<name>` (any `/<size>` suffix dropped), so that a profile
+    holds the same tree on the device trace's clock. The clock reads of a
+    span lie inside its annotation."""
+
+    def __init__(self, tracer, clock, t0: float):
+        self.tracer, self.clock, self.t0 = tracer, clock, t0
+        self.stack: list[_OpenSpan] = []
+        self.next_id = 0
+        # running totals that a step span's args are the growth of
+        self.prefills = 0
+        self.decode_steps = 0
+
+    @contextlib.contextmanager
+    def span(self, name: str, cat: str, timed: bool):
+        """A timed span reads the clock on entry and exit; an untimed one
+        is emitted only if its body calls `done` with the bounds, and the
+        spans inside it only with it."""
+        sp = _OpenSpan(name, cat, self.next_id,
+                       self.stack[-1].id if self.stack else None, timed)
+        self.next_id += 1
+        with jax.profiler.TraceAnnotation("engine." + name.split("/")[0]):
+            if timed:
+                sp.t_start = self.clock()
+            self.stack.append(sp)
+            try:
+                yield sp
+            finally:
+                self.stack.pop()
+                if timed:
+                    sp.t_end = self.clock()
+        if sp.t_end is not None:
+            self._emit(sp)
+
+    def _emit(self, sp: _OpenSpan) -> None:
+        for outer in reversed(self.stack):
+            if not outer.timed:
+                outer.held.append(sp)
+                return
+        if sp.cat == "prefill":
+            self.prefills += 1
+        elif sp.cat == "decode":
+            self.decode_steps += sp.args["steps"]
+        self.tracer.on_span(sp.name, ts=sp.t_start - self.t0,
+                            dur=sp.t_end - sp.t_start, cat=sp.cat,
+                            id=sp.id, parent=sp.parent, **sp.args)
+        for child in sp.held:
+            self._emit(child)
+
+
 class ServeEngine:
     def __init__(self, model: Model, params, slots: int = 4,
                  max_len: int = 512, src_len: int = 0,
@@ -134,8 +215,13 @@ class ServeEngine:
         self.eos_id = eos_id
         # optional duck-typed event sink (tenancy.ServeTraceRecorder): gets
         # on_prefill(rid, prompt_len) / on_decode(lanes, contexts) in the
-        # engine's step-locked order, and (if it defines on_span) one timed
-        # span per device call for the Perfetto export (obs/export.py)
+        # engine's step-locked order, and (if it defines on_span) the span
+        # tree of every step for the Perfetto export (obs/export.py):
+        # step > admit, decode.prep, decode/chunk{n}, decode.retire; the
+        # device calls prefill/* (category "prefill") and decode/*
+        # ("decode") with their dispatch and sync; the rest category
+        # "engine". Without such a tracer no span is made and no
+        # annotation opened.
         self.tracer = tracer
         # optional obs.metrics.MetricsRegistry. Recording is host-side
         # bookkeeping on values the engine already has at each chunk
@@ -242,6 +328,8 @@ class ServeEngine:
         # deadline-aware chunk capping below sizes chunks with it
         self._sec_per_tok = Ewma(alpha=0.3)
         self._t0 = self._clock()
+        self._tree = (_SpanTree(tracer, self._clock, self._t0)
+                      if hasattr(tracer, "on_span") else None)
 
     # -- fault boundary -------------------------------------------------
     def _sleep(self, seconds: float) -> None:
@@ -328,13 +416,19 @@ class ServeEngine:
                                      where=where).inc(len(pairs))
 
     # -- telemetry ------------------------------------------------------
-    def _span(self, name: str, cat: str, t_start: float, t_end: float,
-              **args) -> None:
-        """Emit a timed span to the tracer (engine-relative wall clock);
-        no-op unless the tracer understands spans (on_span)."""
-        if self.tracer is not None and hasattr(self.tracer, "on_span"):
-            self.tracer.on_span(name, ts=t_start - self._t0,
-                                dur=t_end - t_start, cat=cat, **args)
+    def _span(self, name: str, cat: str = "engine", timed: bool = True):
+        """A span of the engine's tree (see _SpanTree), or a shared null
+        context while no tracer takes spans."""
+        if self._tree is None:
+            return _OFF
+        return self._tree.span(name, cat, timed)
+
+    def _compiled(self, fn: str, grew: bool) -> bool:
+        """Pass on whether a device call compiled (its jit cache grew),
+        counting it as serve.compiles{fn=prefill|decode}."""
+        if grew and self.metrics is not None:
+            self.metrics.counter("serve.compiles", fn=fn).inc()
+        return grew
 
     def _observe_prefill(self, path: str, tokens: int, lanes: int,
                         seconds: float) -> None:
@@ -360,16 +454,6 @@ class ServeEngine:
         m.gauge("serve.slot_occupancy").set(lanes / self.slots)
         m.gauge("serve.decode.live_lanes_end").set(live_end)
         m.gauge("serve.queue_depth").set(len(self.queue))
-        if emitted:
-            # honest next-token wait: every token delivered at this chunk's
-            # host sync waited the chunk's full wall time (the p50/p99 the
-            # serving benchmark reports, now live)
-            m.histogram("serve.decode.token_wait_us").record(
-                seconds * 1e6, n=emitted)
-        tok = m.counter("serve.decode.tokens").value
-        sec = m.counter("serve.decode.seconds").value
-        if sec > 0:
-            m.gauge("serve.decode.tok_s").set(tok / sec)
 
     # -- request flow --------------------------------------------------
     def submit(self, req: Request) -> None:
@@ -395,48 +479,49 @@ class ServeEngine:
         return min(b, self.max_len)
 
     def _admit(self) -> None:
-        # queue sweep first: expire queued-past-deadline, shed predicted
-        # misses (slo-aware), and order the queue per policy. Pure host
-        # work; a fifo queue with no deadlines passes through untouched.
-        self.admission.sweep(self.queue, self._clock())
-        while self.queue:
-            free = self._free_slots()
-            if not free:
-                return
-            if not self.bucketed or self.queue[0].extras:
-                # extras carry per-request shapes (e.g. frames) that can't
-                # join a shared bucket batch: prefill them exact-length
-                self._prefill_into(free[0], self.queue.pop(0))
-                continue
-            # group the head-of-queue bucket: every queued request of the
-            # same bucket rides the same prefill call (up to free slots)
-            b = self._bucket(len(self.queue[0].prompt))
-            take: list[Request] = []
-            rest: list[Request] = []
-            for r in self.queue:
-                if len(take) < len(free) and not r.extras and \
-                        self._bucket(len(r.prompt)) == b:
-                    if self._pool is not None:
-                        # paged admission: a lane starts only if its
-                        # worst-case page count (prompt + clamped budget +
-                        # one chunk of inert-write slack) reserves now —
-                        # the per-chunk mapping then can never fail.
-                        # Requests that don't fit wait queued for pages.
-                        worst = self._pool.worst_pages(
-                            len(r.prompt), self._clamped_budget(r))
-                        if not self._pool.can_reserve(worst):
-                            rest.append(r)
-                            continue
-                        self._pool.reserve(free[len(take)], worst)
-                    take.append(r)
-                else:
-                    rest.append(r)
-            self.queue = rest
-            if not take:
-                # head bucket blocked on pages this quantum; retires at
-                # the next chunk sync will free some
-                return
-            self._prefill_group(take, free[: len(take)], b)
+        with self._span("admit"):
+            # queue sweep first: expire queued-past-deadline, shed predicted
+            # misses (slo-aware), and order the queue per policy. Pure host
+            # work; a fifo queue with no deadlines passes through untouched.
+            self.admission.sweep(self.queue, self._clock())
+            while self.queue:
+                free = self._free_slots()
+                if not free:
+                    return
+                if not self.bucketed or self.queue[0].extras:
+                    # extras carry per-request shapes (e.g. frames) that can't
+                    # join a shared bucket batch: prefill them exact-length
+                    self._prefill_into(free[0], self.queue.pop(0))
+                    continue
+                # group the head-of-queue bucket: every queued request of the
+                # same bucket rides the same prefill call (up to free slots)
+                b = self._bucket(len(self.queue[0].prompt))
+                take: list[Request] = []
+                rest: list[Request] = []
+                for r in self.queue:
+                    if len(take) < len(free) and not r.extras and \
+                            self._bucket(len(r.prompt)) == b:
+                        if self._pool is not None:
+                            # paged admission: a lane starts only if its
+                            # worst-case page count (prompt + clamped budget +
+                            # one chunk of inert-write slack) reserves now —
+                            # the per-chunk mapping then can never fail.
+                            # Requests that don't fit wait queued for pages.
+                            worst = self._pool.worst_pages(
+                                len(r.prompt), self._clamped_budget(r))
+                            if not self._pool.can_reserve(worst):
+                                rest.append(r)
+                                continue
+                            self._pool.reserve(free[len(take)], worst)
+                        take.append(r)
+                    else:
+                        rest.append(r)
+                self.queue = rest
+                if not take:
+                    # head bucket blocked on pages this quantum; retires at
+                    # the next chunk sync will free some
+                    return
+                self._prefill_group(take, free[: len(take)], b)
 
     # -- bucketed prefill ------------------------------------------------
     def _probe_batch_axes(self):
@@ -466,72 +551,68 @@ class ServeEngine:
 
     def _prefill_group(self, reqs: list[Request], slot_list: list[int],
                        bucket: int) -> None:
-        toks = np.zeros((self.slots, bucket), np.int32)
-        true_lens = np.ones(self.slots, np.int32)      # pad lanes: len 1
-        slot_ids = np.full(self.slots, -1, np.int32)
-        for g, (r, s) in enumerate(zip(reqs, slot_list)):
-            S = len(r.prompt)
-            toks[g, :S] = r.prompt
-            true_lens[g] = S
-            slot_ids[g] = s
-        args = [jnp.asarray(toks), jnp.asarray(slot_ids),
-                jnp.asarray(true_lens)]
-        if self._pool is not None:
-            # map each lane's prompt pages, then hand the impl a LANE-
-            # indexed destination table (row g = lane g's pages, sentinel-
-            # padded) for the page-granular scatter. The slot-indexed
-            # device page_table is pushed separately before the next
-            # decode chunk (step() checks pool.dirty).
-            dest = np.full((self.slots, self._pool.pages_per_lane),
-                           self._pool.sentinel, np.int32)
+        with self._span("prefill.pack"):
+            toks = np.zeros((self.slots, bucket), np.int32)
+            true_lens = np.ones(self.slots, np.int32)  # pad lanes: len 1
+            slot_ids = np.full(self.slots, -1, np.int32)
             for g, (r, s) in enumerate(zip(reqs, slot_list)):
-                self._pool.map_to(s, len(r.prompt))
-                own = self._pool.owned(s)
-                dest[g, :len(own)] = own
-            args.append(jnp.asarray(dest))
+                S = len(r.prompt)
+                toks[g, :S] = r.prompt
+                true_lens[g] = S
+                slot_ids[g] = s
+            args = [jnp.asarray(toks), jnp.asarray(slot_ids),
+                    jnp.asarray(true_lens)]
+            if self._pool is not None:
+                # map each lane's prompt pages, then hand the impl a LANE-
+                # indexed destination table (row g = lane g's pages,
+                # sentinel-padded) for the page-granular scatter. The
+                # slot-indexed device page_table is pushed separately
+                # before the next decode chunk (step() checks pool.dirty).
+                dest = np.full((self.slots, self._pool.pages_per_lane),
+                               self._pool.sentinel, np.int32)
+                for g, (r, s) in enumerate(zip(reqs, slot_list)):
+                    self._pool.map_to(s, len(r.prompt))
+                    own = self._pool.owned(s)
+                    dest[g, :len(own)] = own
+                args.append(jnp.asarray(dest))
         self._buckets_seen.add(bucket)
-        t_start = self._clock()
-        try:
-            if self._guard_on:
-                def call():
-                    first, cache, gstats = self._prefill_fn(
-                        self.params, args[0], self.cache, *args[1:],
-                        self._sdc_arr())
-                    flags = np.asarray(gstats)
-                    if int(flags[1]) > 0:
-                        raise SilentCorruption(
-                            f"prefill: {int(flags[1])} uncorrected "
-                            f"corruption(s) detected")
-                    return first, cache, int(flags[0])
-                first, cache, corrected = self._device_call("prefill", call)
-                self._note_guard(corrected)
-            else:
-                first, cache = self._device_call(
-                    "prefill", lambda: self._prefill_fn(
-                        self.params, args[0], self.cache, *args[1:]))
-        except PermanentFault:
-            # the whole group failed before any state was assigned: shed
-            # the requests (terminal `rejected`), slots stay free and
-            # their page reservations return to the pool
-            self._reject_group(reqs, "device-fault")
-            self._release_group(slot_list, len(reqs))
-            return
-        except SilentCorruption:
-            self.guard_events["uncorrectable"] += 1
-            self._reject_group(reqs, "sdc-uncorrectable")
-            self._release_group(slot_list, len(reqs))
-            return
-        self.cache = cache
-        first = np.asarray(first)
-        t_end = self._clock()
+        jit_before = self._prefill_fn._cache_size()
+        with self._span(f"prefill/bucket{bucket}", "prefill",
+                        timed=False) as sp:
+            t_start = self._clock()
+            try:
+                with self._span("prefill.dispatch"):
+                    first, cache = self._launch_prefill(args)
+            except PermanentFault:
+                # the whole group failed before any state was assigned:
+                # shed the requests (terminal `rejected`), slots stay free
+                # and their page reservations return to the pool
+                self._reject_group(reqs, "device-fault")
+                self._release_group(slot_list, len(reqs))
+                return
+            except SilentCorruption:
+                self.guard_events["uncorrectable"] += 1
+                self._reject_group(reqs, "sdc-uncorrectable")
+                self._release_group(slot_list, len(reqs))
+                return
+            with self._span("prefill.sync"):
+                self.cache = cache
+                first = np.asarray(first)
+            t_end = self._clock()
+            n_tokens = int(sum(len(r.prompt) for r in reqs))
+            compiled = self._compiled(
+                "prefill", self._prefill_fn._cache_size() > jit_before)
+            if sp is not None:
+                # the group's own lanes are activated only below
+                sp.done(t_start, t_end, bucket=bucket, lanes=len(reqs),
+                        tokens=n_tokens, rids=[r.rid for r in reqs],
+                        stalled_lanes=sum(r is not None
+                                          for r in self.active),
+                        compiled=compiled)
         if self.tracer is not None:
             for r in reqs:       # successful work only enters the trace
                 self.tracer.on_prefill(r.rid, len(r.prompt),
                                        t=t_start - self._t0)
-        n_tokens = int(sum(len(r.prompt) for r in reqs))
-        self._span(f"prefill/bucket{bucket}", "prefill", t_start, t_end,
-                   bucket=bucket, lanes=len(reqs), tokens=n_tokens,
-                   rids=[r.rid for r in reqs])
         self._observe_prefill("bucketed", n_tokens, len(reqs),
                               t_end - t_start)
         # a lane whose prefill logits were non-finite is encoded as a -1
@@ -554,6 +635,29 @@ class ServeEngine:
             self.admission.note_admitted(r, t_end)
             r._jit_epoch = self._jit_sizes()
             self._retire_if_full(s)
+
+    def _launch_prefill(self, args: list) -> tuple:
+        """The jitted bucketed prefill through the fault boundary: its
+        first tokens (on the device) and the new cache. Raises what
+        _device_call raises."""
+        if not self._guard_on:
+            return self._device_call(
+                "prefill", lambda: self._prefill_fn(
+                    self.params, args[0], self.cache, *args[1:]))
+
+        def call():
+            first, cache, gstats = self._prefill_fn(
+                self.params, args[0], self.cache, *args[1:],
+                self._sdc_arr())
+            flags = np.asarray(gstats)
+            if int(flags[1]) > 0:
+                raise SilentCorruption(
+                    f"prefill: {int(flags[1])} uncorrected "
+                    f"corruption(s) detected")
+            return first, cache, int(flags[0])
+        first, cache, corrected = self._device_call("prefill", call)
+        self._note_guard(corrected)
+        return first, cache
 
     def _prefill_forward(self, params, tokens, true_lens, sdc):
         """Shared body of both prefill impls: forward over a dense
@@ -636,34 +740,46 @@ class ServeEngine:
         encoder-decoder cross-KV lanes line up with the batched cache
         (regression: the seed dropped src_len here)."""
         S = len(req.prompt)
-        self._buckets_seen.add(S)     # exact-length path: one shape per len
-        t_start = self._clock()
-        lane_cache = self.model.init_cache(1, self.max_len,
-                                           src_len=self.src_len)
-        batch = {"tokens": jnp.asarray(req.prompt)[None, :]}
-        for key, val in req.extras.items():
-            batch[key] = jnp.asarray(val)
-        try:
-            logits, lane_cache = self._device_call(
-                "prefill",
-                lambda: self.model.prefill(self.params, batch, lane_cache))
-        except PermanentFault:
-            self._reject_group([req], "device-fault")
-            return
-        # fold the finiteness check into the one value already synced:
-        # a poisoned lane yields -1 and is shed before slot activation
-        first = jnp.argmax(logits[0]).astype(jnp.int32)
-        first = int(jnp.where(jnp.isfinite(logits[0]).all(), first, -1))
+        new_len = S not in self._buckets_seen   # one shape per length
+        self._buckets_seen.add(S)
+        with self._span(f"prefill/exact{S}", "prefill", timed=False) as sp:
+            t_start = self._clock()
+            with self._span("prefill.pack"):
+                lane_cache = self.model.init_cache(1, self.max_len,
+                                                   src_len=self.src_len)
+                batch = {"tokens": jnp.asarray(req.prompt)[None, :]}
+                for key, val in req.extras.items():
+                    batch[key] = jnp.asarray(val)
+            try:
+                with self._span("prefill.dispatch"):
+                    logits, lane_cache = self._device_call(
+                        "prefill", lambda: self.model.prefill(
+                            self.params, batch, lane_cache))
+            except PermanentFault:
+                self._reject_group([req], "device-fault")
+                return
+            # fold the finiteness check into the one value already synced:
+            # a poisoned lane yields -1 and is shed before slot activation
+            with self._span("prefill.sync"):
+                first = jnp.argmax(logits[0]).astype(jnp.int32)
+                first = int(jnp.where(jnp.isfinite(logits[0]).all(), first,
+                                      -1))
+            if first >= 0:
+                self.cache = _write_lane(self.cache, lane_cache, slot)
+                req.out.append(first)
+            t_end = self._clock()
+            compiled = self._compiled("prefill", new_len)
+            if sp is not None:
+                sp.done(t_start, t_end, bucket=S, lanes=1, tokens=S,
+                        rids=[req.rid],
+                        stalled_lanes=sum(r is not None
+                                          for r in self.active),
+                        compiled=compiled)
         if first < 0:
             self._shed_non_finite([(req, slot)], where="prefill")
             return
-        self.cache = _write_lane(self.cache, lane_cache, slot)
-        req.out.append(first)
-        t_end = self._clock()
         if self.tracer is not None:
             self.tracer.on_prefill(req.rid, S, t=t_start - self._t0)
-        self._span(f"prefill/exact{S}", "prefill", t_start, t_end,
-                   bucket=S, lanes=1, tokens=S, rids=[req.rid])
         self._observe_prefill("exact", S, 1, t_end - t_start)
         self.active[slot] = req
         self.positions[slot] = S
@@ -808,77 +924,119 @@ class ServeEngine:
 
     def step(self) -> int:
         """One scheduling quantum: admission, then one fused decode chunk.
-        Returns the number of lanes live at the chunk start."""
+        Returns the number of lanes live at the chunk start. With a tracer
+        that takes spans, the quantum is a root `step` span whose args
+        count its prefill calls and decode steps."""
+        tree = self._tree
+        if tree is None:
+            return self._step()
+        prefills, decode_steps = tree.prefills, tree.decode_steps
+        with tree.span("step", "engine", timed=True) as sp:
+            live = self._step()
+            sp.args.update(prefills=tree.prefills - prefills,
+                           decode_steps=tree.decode_steps - decode_steps)
+        return live
+
+    def _step(self) -> int:
         self._admit()
         live = [i for i, r in enumerate(self.active) if r is not None]
         if not live:
             return 0
-        n = self._chunk_len(live)
-        if self._pool is not None:
-            # map pages to cover this chunk's appends (live lanes reach
-            # pos+n; a lane that dies mid-chunk writes inertly inside the
-            # same bound — covered by its reservation's chunk slack), then
-            # push the refreshed slot-indexed table if anything changed.
-            # Host-side work + one async host->device transfer: no syncs.
+        with self._span("decode.prep"):
+            n = self._chunk_len(live)
+            if self._pool is not None:
+                # map pages to cover this chunk's appends (live lanes reach
+                # pos+n; a lane that dies mid-chunk writes inertly inside
+                # the same bound — covered by its reservation's chunk
+                # slack), then push the refreshed slot-indexed table if
+                # anything changed. Host-side work + one async host->device
+                # transfer: no syncs.
+                for i in live:
+                    self._pool.map_to(i, int(self.positions[i]) + n)
+                if self._pool.dirty:
+                    self.cache = self._with_table(self.cache)
+            toks = np.zeros(self.slots, np.int32)
+            alive0 = np.zeros(self.slots, bool)
             for i in live:
-                self._pool.map_to(i, int(self.positions[i]) + n)
-            if self._pool.dirty:
-                self.cache = self._with_table(self.cache)
-        toks = np.zeros(self.slots, np.int32)
-        alive0 = np.zeros(self.slots, bool)
-        for i in live:
-            toks[i] = self.active[i].out[-1]
-            alive0[i] = True
-        pos0 = self.positions.copy()
-        t_start = self._clock()
-        try:
-            if self._guard_on:
-                def call():
-                    cache, seq, emits, stats = self._decode_fn(
-                        self.params, self.cache, jnp.asarray(toks),
-                        jnp.asarray(pos0), jnp.asarray(self.budgets),
-                        jnp.asarray(alive0), self._sdc_arr(), n=n)
-                    flags = np.asarray(stats)
-                    if int(flags[-1]) > 0:
-                        raise SilentCorruption(
-                            f"decode chunk: {int(flags[-1])} uncorrected "
-                            f"corruption(s) detected")
-                    return cache, seq, emits, flags
-                cache, seq, emits, stats = self._device_call("decode", call)
-                self._note_guard(int(stats[-2]))
-            else:
-                cache, seq, emits, stats = self._device_call(
-                    "decode", lambda: self._decode_fn(
-                        self.params, self.cache, jnp.asarray(toks),
-                        jnp.asarray(pos0), jnp.asarray(self.budgets),
-                        jnp.asarray(alive0), n=n))
-        except PermanentFault:
-            # the chunk never ran (the injector raises before launch):
-            # cache/positions are untouched. Shed the affected lanes and
-            # free their slots so queued work keeps flowing.
-            self._reject_group([self.active[i] for i in live],
-                               "device-fault")
-            for i in live:
-                self._release_slot(i)
-            return len(live)
-        except SilentCorruption:
-            # every retry recomputed the same corrupted chunk; no state
-            # was assigned, so the lanes are intact but unservable —
-            # finalize them as sdc-uncorrectable and free the slots
-            self.guard_events["uncorrectable"] += 1
-            self._reject_group([self.active[i] for i in live],
-                               "sdc-uncorrectable")
-            for i in live:
-                self._release_slot(i)
-            return len(live)
-        self.cache = cache
-        seq = np.asarray(seq)                         # the ONE host sync
-        emits = np.asarray(emits)
-        stats = np.asarray(stats)     # device accumulators, already ready
-        t_end = self._clock()
-        self._span(f"decode/chunk{n}", "decode", t_start, t_end,
-                   steps=n, lanes=len(live), tokens=int(stats[0]),
-                   live_end=int(stats[1]))
+                toks[i] = self.active[i].out[-1]
+                alive0[i] = True
+            pos0 = self.positions.copy()
+        jit_before = self._decode_fn._cache_size()
+        with self._span(f"decode/chunk{n}", "decode", timed=False) as sp:
+            t_start = self._clock()
+            try:
+                with self._span("decode.dispatch"):
+                    cache, seq, emits, stats = self._launch_decode(
+                        toks, pos0, alive0, n)
+            except PermanentFault:
+                # the chunk never ran (the injector raises before launch):
+                # cache/positions are untouched. Shed the affected lanes
+                # and free their slots so queued work keeps flowing.
+                self._reject_group([self.active[i] for i in live],
+                                   "device-fault")
+                for i in live:
+                    self._release_slot(i)
+                return len(live)
+            except SilentCorruption:
+                # every retry recomputed the same corrupted chunk; no state
+                # was assigned, so the lanes are intact but unservable —
+                # finalize them as sdc-uncorrectable and free the slots
+                self.guard_events["uncorrectable"] += 1
+                self._reject_group([self.active[i] for i in live],
+                                   "sdc-uncorrectable")
+                for i in live:
+                    self._release_slot(i)
+                return len(live)
+            with self._span("decode.sync"):
+                self.cache = cache
+                seq = np.asarray(seq)                 # the ONE host sync
+                emits = np.asarray(emits)
+                stats = np.asarray(stats)   # device accumulators, ready
+            t_end = self._clock()
+            compiled = self._compiled(
+                "decode", self._decode_fn._cache_size() > jit_before)
+            if sp is not None:
+                sp.done(t_start, t_end, steps=n, lanes=len(live),
+                        tokens=int(stats[0]), live_end=int(stats[1]),
+                        rids=[self.active[i].rid for i in live],
+                        compiled=compiled)
+        with self._span("decode.retire"):
+            self._retire_chunk(live, n, pos0, seq, emits, stats, t_start,
+                               t_end)
+        return len(live)
+
+    def _launch_decode(self, toks, pos0, alive0, n: int) -> tuple:
+        """The jitted decode chunk through the fault boundary: the new
+        cache, the chunk's tokens, emit masks and stats (on the device,
+        the stats on the host under the guard). Raises what _device_call
+        raises."""
+        if not self._guard_on:
+            return self._device_call(
+                "decode", lambda: self._decode_fn(
+                    self.params, self.cache, jnp.asarray(toks),
+                    jnp.asarray(pos0), jnp.asarray(self.budgets),
+                    jnp.asarray(alive0), n=n))
+
+        def call():
+            cache, seq, emits, stats = self._decode_fn(
+                self.params, self.cache, jnp.asarray(toks),
+                jnp.asarray(pos0), jnp.asarray(self.budgets),
+                jnp.asarray(alive0), self._sdc_arr(), n=n)
+            flags = np.asarray(stats)
+            if int(flags[-1]) > 0:
+                raise SilentCorruption(
+                    f"decode chunk: {int(flags[-1])} uncorrected "
+                    f"corruption(s) detected")
+            return cache, seq, emits, flags
+        cache, seq, emits, stats = self._device_call("decode", call)
+        self._note_guard(int(stats[-2]))
+        return cache, seq, emits, stats
+
+    def _retire_chunk(self, live: list[int], n: int, pos0, seq, emits,
+                      stats, t_start: float, t_end: float) -> None:
+        """The host's work on a synced chunk: telemetry, the tracer's
+        step-locked replay, retire with κ calibration, shedding of
+        non-finite lanes, expiry and in-chunk recycling."""
         self._observe_decode(n, len(live), int(stats[0]), int(stats[1]),
                              t_end - t_start)
         emitted = int(stats[0])
@@ -954,7 +1112,6 @@ class ServeEngine:
             self.recycled += max(
                 0, sum(r is not None for r in self.active) - occupied)
         self._observe_paged()
-        return len(live)
 
     def _with_table(self, cache):
         """Push the pool's slot-indexed page table into every paged leaf

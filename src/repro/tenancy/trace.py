@@ -48,10 +48,12 @@ class ServeTraceRecorder:
     stream.
 
     Events carry the GEMM-shaping facts (what `trace_to_gemms` lowers);
-    `spans` additionally carry the host wall-clock of every device call
-    the engine made (one span per prefill launch / fused decode chunk),
-    which `obs.export.to_chrome_trace` turns into a Perfetto-loadable
-    timeline and `obs.drift` pairs with the wave-model prediction.
+    `spans` additionally carry the host wall-clock of the engine's span
+    tree: every step, and within it every device call (category
+    "prefill" per prefill launch, "decode" per fused decode chunk) and the
+    host work around them (category "engine"). `obs.export.to_chrome_trace`
+    turns them into a Perfetto-loadable timeline; `phase_seconds` sums one
+    category.
     """
 
     events: list[tuple] = dataclasses.field(default_factory=list)
