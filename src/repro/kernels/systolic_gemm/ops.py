@@ -17,6 +17,7 @@ independent GEMMs in one kernel launch (MoE experts / multi-tenant pods).
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -35,14 +36,31 @@ def _pad_to(x, m, axis):
     return jnp.pad(x, widths)
 
 
-def _auto_blocks(m: int, k: int, n: int, dtype, out_dtype
+def _auto_blocks(m: int, k: int, n: int, dtype, out_dtype, head_dim: int = 0
                  ) -> tuple[int, int, int]:
     """DSE-tuned block geometry (lazy import keeps kernels importable
     without the parallel/ package and avoids a module cycle)."""
     from ...parallel.autoshard import choose_blocks
     return choose_blocks(m, k, n,
                          dtype_bytes=jnp.dtype(dtype).itemsize,
-                         out_bytes=jnp.dtype(out_dtype).itemsize)
+                         out_bytes=jnp.dtype(out_dtype).itemsize,
+                         head_dim=head_dim)
+
+
+def _streams(w, n: int, blocks: tuple[int, int, int]) -> bool:
+    """Whether the kernel can read a stack's layer in place at these
+    (clipped) blocks: they divide K and N (a stack is never padded), and
+    a stack stored as [L, K, H, D] has lane-wide heads and blocks of
+    whole groups of heads (autoshard.head_blocks)."""
+    from ...parallel.autoshard import head_blocks
+    _, bn, bk = blocks
+    if w.shape[1] % bk or n % bn:
+        return False
+    if w.ndim == 3:
+        return True
+    d = w.shape[3]
+    return d % 128 == 0 and bn in head_blocks(
+        n, d, jnp.dtype(w.dtype).itemsize, (bn,))
 
 
 def _auto_blocks_grouped(g: int, m: int, k: int, n: int, dtype, out_dtype
@@ -60,7 +78,8 @@ def _auto_blocks_grouped(g: int, m: int, k: int, n: int, dtype, out_dtype
     jax.jit,
     static_argnames=("activation", "block_m", "block_n", "block_k",
                      "out_dtype", "interpret"))
-def systolic_gemm(x, w, scale=None, bias=None, *, activation=None,
+def systolic_gemm(x, w, scale=None, bias=None, *, layer=None,
+                  activation=None,
                   block_m: int | None = None, block_n: int | None = None,
                   block_k: int | None = None,
                   out_dtype=jnp.float32, interpret: bool | None = None):
@@ -69,19 +88,33 @@ def systolic_gemm(x, w, scale=None, bias=None, *, activation=None,
     int8 x int8 -> int32 accumulate; bf16/f32 -> f32 accumulate.
     The fused epilogue is the paper's SIMD post-processor (DESIGN.md §2).
     Blocks default to the tile_stats autotuner (choose_blocks).
+
+    With `layer` (an int32 scalar, traced or not), w is a layer stack
+    [L, K, N], or [L, K, H, D] for a weight stored per head (N = H x D),
+    and the product uses w[layer]: the kernel streams that layer's blocks
+    from the stack, which is never padded or relayouted (either would
+    copy all of it). Where the blocks cannot stream (`_streams`), the
+    layer is sliced out and takes the per-layer path.
     """
     if interpret is None:
         interpret = interpret_mode()
     M, K = x.shape
-    N = w.shape[1]
+    N = math.prod(w.shape[1 if layer is None else 2:])
+    if w.ndim == 4 and w.shape[2] == 1:      # one head: drop its unit axis
+        w = w.reshape(w.shape[:2] + w.shape[3:])
+    head_dim = w.shape[3] if w.ndim == 4 else 0
     if block_m is None or block_n is None or block_k is None:
-        am, an, ak = _auto_blocks(M, K, N, x.dtype, out_dtype)
+        am, an, ak = _auto_blocks(M, K, N, x.dtype, out_dtype, head_dim)
         block_m, block_n, block_k = (block_m or am, block_n or an,
                                      block_k or ak)
     bm, bn, bk = (min(block_m, _rup(M)), min(block_n, _rup(N)),
                   min(block_k, _rup(K)))
+    if layer is not None and not _streams(w, N, (bm, bn, bk)):
+        w = jax.lax.dynamic_index_in_dim(w, layer, 0, keepdims=False)
+        w = w.reshape(K, N)
+        layer = None
     xp = _pad_to(_pad_to(x, bm, 0), bk, 1)
-    wp = _pad_to(_pad_to(w, bk, 0), bn, 1)
+    wp = w if layer is not None else _pad_to(_pad_to(w, bk, 0), bn, 1)
     if scale is None:
         scale = jnp.ones((N,), jnp.float32)
     if bias is None:
@@ -89,7 +122,7 @@ def systolic_gemm(x, w, scale=None, bias=None, *, activation=None,
     sp = _pad_to(scale, bn, 0)
     bp = _pad_to(bias, bn, 0)
     out = systolic_gemm_pallas(
-        xp, wp, sp, bp, block_m=bm, block_n=bn, block_k=bk,
+        xp, wp, sp, bp, layer=layer, block_m=bm, block_n=bn, block_k=bk,
         activation=activation, out_dtype=out_dtype, interpret=interpret)
     return out[:M, :N]
 
@@ -97,8 +130,10 @@ def systolic_gemm(x, w, scale=None, bias=None, *, activation=None,
 def fused_lane_gemm(x, w, scale=None, bias=None, *, activation=None,
                     out_dtype=None, interpret: bool | None = None,
                     block_m: int | None = None, block_n: int | None = None,
-                    block_k: int | None = None, guard=None):
-    """Fused-lane GEMM: x [..., K] @ w [K, N] -> [..., N].
+                    block_k: int | None = None, guard=None, layer=None):
+    """Fused-lane GEMM: x [..., K] @ w [K, N] -> [..., N]; with `layer`,
+    w is a stack [L, K, N] or [L, K, H, D] read in place (see
+    `systolic_gemm`).
 
     All leading axes of x (decode lanes, sequence positions, batch) fuse
     into the GEMM M axis — one pod GEMM instead of a fan of GEMVs, which
@@ -117,15 +152,19 @@ def fused_lane_gemm(x, w, scale=None, bias=None, *, activation=None,
     out_dtype = jnp.float32 if out_dtype is None else out_dtype
     if guard is not None and guard.mode != "off":
         from .guard import guarded_gemm
+        if layer is not None:
+            w = jax.lax.dynamic_index_in_dim(w, layer, 0, keepdims=False)
+            w = w.reshape(w.shape[0], -1)
         out = guarded_gemm(
             x.reshape(m, x.shape[-1]), w, scale, bias, guard=guard,
             activation=activation, out_dtype=out_dtype, interpret=interpret)
     else:
         out = systolic_gemm(
-            x.reshape(m, x.shape[-1]), w, scale, bias, activation=activation,
+            x.reshape(m, x.shape[-1]), w, scale, bias, layer=layer,
+            activation=activation,
             block_m=block_m, block_n=block_n, block_k=block_k,
             out_dtype=out_dtype, interpret=interpret)
-    return out.reshape(lead + (w.shape[1],))
+    return out.reshape(lead + (out.shape[-1],))
 
 
 @functools.partial(

@@ -35,11 +35,23 @@ cols=block_n), k_part=block_m)`` returns exactly this kernel's grid counts:
 ``n_i = M/block_m`` x ``n_l = N/block_n`` x ``n_j = K/block_k`` (the RAW
 psum-chain depth carried by the accumulator scratch). `choose_blocks`
 scores every candidate geometry with a roofline over those counts —
-max(padded-MAC compute, HBM block traffic) — and rejects candidates whose
-VMEM working set (double-buffered x/w streaming blocks + accumulator +
-output block) exceeds the budget (default 12 MiB of the ~16 MiB VMEM).
-Results are lru-cached per (shape, dtype), so the serving hot loop pays
-for an autotune once per distinct layer shape.
+max(padded-MAC compute, HBM block traffic) — plus the stream's exposed
+first and last blocks and a fixed cost per grid step, and rejects
+candidates whose VMEM working set (double-buffered x/w and output blocks
++ accumulator + one block of 32-bit temporaries) exceeds the budget
+(default 12 MiB of the ~16 MiB scoped VMEM). A dimension's candidates are
+the 128-multiples that divide it, so it is never padded (a small-M
+decode stream takes blocks as long as K); where none divides it, 128,
+256 and 512. Results are lru-cached per (shape, dtype), so the serving
+hot loop pays for an autotune once per distinct layer shape.
+
+Stacked weights: `systolic_gemm_pallas(..., layer=i)` takes w as a stack
+of all layers' weights, [L, K, N] or [L, K, H, D] (a per-head weight as
+stored), and a layer index. The index is a scalar-prefetch operand that
+the w BlockSpec puts on the squeezed layer axis, so each grid step DMAs
+the layer's block straight from the stack: a layer scan never copies a
+layer's weights out first (XLA cannot fuse a slice into a Pallas call's
+operand).
 
 The grouped variant (`grouped_systolic_gemm_pallas`) adds a leading
 group axis to the grid — G independent (M x K) @ (K x N) problems in one
@@ -58,6 +70,7 @@ bytes, same grid walk), so `choose_blocks` scores it identically.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -109,7 +122,10 @@ def _gemm_kernel(x_ref, w_ref, scale_ref, bias_ref, o_ref, acc_ref, *,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    _accumulate(x_ref[...], w_ref[...], acc_ref)
+    w = w_ref[...]
+    if w.ndim == 3:                  # [bk, heads, hd] as stored: relayout
+        w = w.reshape(w.shape[0], -1)    # in VMEM to the [bk, bn] block
+    _accumulate(x_ref[...], w, acc_ref)
 
     @pl.when(k == n_k - 1)
     def _epilogue():
@@ -120,10 +136,11 @@ def _gemm_kernel(x_ref, w_ref, scale_ref, bias_ref, o_ref, acc_ref, *,
 
 def systolic_gemm_pallas(
     x: jax.Array,                  # [M, K] int8 | bf16
-    w: jax.Array,                  # [K, N] int8 | bf16
+    w: jax.Array,                  # [K, N], or a stack [L, K, N] | [L, K, H, D]
     scale: jax.Array,              # [N] f32 dequant scale (ones if None)
     bias: jax.Array,               # [N] f32
     *,
+    layer: jax.Array | None = None,   # int32 scalar: the stack's layer
     block_m: int = 256,
     block_n: int = 256,
     block_k: int = 256,
@@ -131,9 +148,20 @@ def systolic_gemm_pallas(
     out_dtype=jnp.float32,
     interpret: bool = False,
 ) -> jax.Array:
+    """out = epilogue((x @ w) * scale + bias).
+
+    With `layer`, w is a stack of layers and the kernel reads layer
+    `layer`'s blocks straight from it: the index is a scalar-prefetch
+    operand that the w BlockSpec's index_map puts on the (squeezed)
+    layer axis, so no slice of the stack is ever copied out. A stack
+    [L, K, H, D] holds each layer's weight as stored for heads
+    ([d, heads, head_dim], N = H x D): its blocks span block_n // D whole
+    heads, and the kernel folds each block to [block_k, block_n] in VMEM,
+    so the [K, N] view never exists in HBM."""
     M, K = x.shape
-    K2, N = w.shape
-    assert K == K2
+    K2 = w.shape[0 if layer is None else 1]
+    N = math.prod(w.shape[1 if layer is None else 2:])
+    assert K == K2 and w.ndim in ((2,) if layer is None else (3, 4))
     assert M % block_m == 0 and N % block_n == 0 and K % block_k == 0, (
         "caller (ops.py) pads to block multiples")
     n_k = K // block_k
@@ -142,23 +170,53 @@ def systolic_gemm_pallas(
     kernel = functools.partial(
         _gemm_kernel, n_k=n_k, activation=activation, out_dtype=out_dtype)
     acc_dtype = jnp.int32 if x.dtype == jnp.int8 else jnp.float32
-    return pl.pallas_call(
-        kernel,
+    # index maps take the scalar-prefetch ref (if any) as a trailing arg
+    if layer is None:
+        w_spec = pl.BlockSpec((block_k, block_n), lambda i, j, k: (k, j))
+    elif w.ndim == 3:
+        w_spec = pl.BlockSpec((None, block_k, block_n),
+                              lambda i, j, k, lyr: (lyr[0], k, j))
+    else:
+        D = w.shape[3]
+        assert block_n % D == 0
+        w_spec = pl.BlockSpec((None, block_k, block_n // D, D),
+                              lambda i, j, k, lyr: (lyr[0], k, j, 0))
+    spec = dict(
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_m, block_k), lambda i, j, k: (i, k)),
-            pl.BlockSpec((block_k, block_n), lambda i, j, k: (k, j)),
-            pl.BlockSpec((1, block_n), lambda i, j, k: (0, j)),
-            pl.BlockSpec((1, block_n), lambda i, j, k: (0, j)),
+            pl.BlockSpec((block_m, block_k), lambda i, j, k, *_: (i, k)),
+            w_spec,
+            pl.BlockSpec((1, block_n), lambda i, j, k, *_: (0, j)),
+            pl.BlockSpec((1, block_n), lambda i, j, k, *_: (0, j)),
         ],
-        out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
+        out_specs=pl.BlockSpec((block_m, block_n),
+                               lambda i, j, k, *_: (i, j)),
         scratch_shapes=[
             # int32/f32 accumulator = the pod's psum registers
             pltpu.VMEM((block_m, block_n), acc_dtype),
         ],
+    )
+    args = (x, w, scale.reshape(1, N), bias.reshape(1, N))
+    if layer is None:
+        grid_spec = pl.GridSpec(**spec)
+    else:
+        grid_spec = pltpu.PrefetchScalarGridSpec(num_scalar_prefetch=1,
+                                                 **spec)
+        kernel = functools.partial(_skip_prefetch, kernel)
+        args = (jnp.reshape(layer, (1,)).astype(jnp.int32),) + args
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         interpret=interpret,
-    )(x, w, scale.reshape(1, N), bias.reshape(1, N))
+    )(*args)
+
+
+def _skip_prefetch(kernel, layer_ref, *refs):
+    """The kernel body of a stacked call: the layer index only steers the
+    w BlockSpec's DMAs, so the body never reads it."""
+    del layer_ref
+    kernel(*refs)
 
 
 def _grouped_gemm_kernel(x_ref, w_ref, scale_ref, bias_ref, o_ref, acc_ref,

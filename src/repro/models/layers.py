@@ -21,7 +21,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-from typing import Any
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -135,6 +135,23 @@ def apply_rope(x, positions, theta: float):
     return out.astype(x.dtype)
 
 
+class LayerSlice(NamedTuple):
+    """Layer `i` of a stacked weight [L, ...], left in the stack: the pod
+    GEMM reads the layer's blocks straight from it (`pod_dense`), so the
+    layer is never copied out. `shape` and `reshape` act on the layer, as
+    they would on the slice."""
+    stack: jax.Array
+    i: jax.Array
+
+    @property
+    def shape(self) -> tuple[int, ...]:
+        return self.stack.shape[1:]
+
+    def reshape(self, *shape) -> "LayerSlice":
+        return LayerSlice(self.stack.reshape(self.stack.shape[:1] + shape),
+                          self.i)
+
+
 def pod_dense(x, w, *, activation: str | None = None):
     """One dense projection on the Pallas systolic pod GEMM.
 
@@ -143,14 +160,19 @@ def pod_dense(x, w, *, activation: str | None = None):
     GEMVs run as the ONE fused [lanes, K] @ [K, N] GEMM the tenancy
     co-scheduling analysis assumes. Trailing axes of w beyond the
     contraction fold into N and unfold on return (e.g. [d, H, hd] heads).
+    w may be a `LayerSlice`, which the GEMM reads in place, a [d, H, hd]
+    layer in its stored layout.
     Block geometry comes from the DSE autotuner
     (parallel.autoshard.choose_blocks, per-shape cached); `activation`
     runs in the kernel's fused epilogue (the paper's SIMD post-processor).
     """
     from ..kernels.systolic_gemm.guard import active_guard
     from ..kernels.systolic_gemm.ops import fused_lane_gemm
-    k = x.shape[-1]
-    out = fused_lane_gemm(x, w.reshape(k, -1), activation=activation,
+    if isinstance(w, LayerSlice):
+        stack, layer = w
+    else:
+        stack, layer = w.reshape(x.shape[-1], -1), None
+    out = fused_lane_gemm(x, stack, activation=activation, layer=layer,
                           out_dtype=x.dtype, guard=active_guard())
     return out.reshape(x.shape[:-1] + w.shape[1:])
 

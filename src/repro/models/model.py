@@ -27,7 +27,7 @@ from .layers import (ParamSpec, apply_norm, cross_entropy_loss, embed,
                      param_count, shapes_from_schema, unembed)
 from .ssm import SSMCache
 from .transformer import (MLACache, Segment, apply_block, block_schema,
-                          segments)
+                          layer_view, segments)
 
 Constrain = Callable[[jax.Array, str], jax.Array]
 
@@ -220,9 +220,18 @@ class Model:
         # serving: carry the stacked cache and update layer i in place —
         # XLA reuses the carry buffer across iterations, so the KV cache
         # costs 1x HBM instead of the 2-3x an xs->ys scan would copy.
+        # On the pod GEMM, an untaped scan closes over the stacked params
+        # and scans the index alone: the GEMMs stream each layer's weights
+        # from the stacks (transformer.layer_view) instead of reading a
+        # copy the scan slices out of them.
+        from ..kernels.systolic_gemm.guard import active_tape
+        stream = self.use_pallas and not self.unroll and active_tape() is None
+
         def body(carry, xs):
             h, cache_st = carry
             p_layer, i = xs
+            if stream:
+                p_layer = layer_view(p_seg, i, cfg)
             cache_l = jax.tree.map(
                 lambda a: jax.lax.dynamic_index_in_dim(a, i, 0,
                                                        keepdims=False),
@@ -236,7 +245,8 @@ class Model:
             return (self.constrain(h, "residual"), cache_st), None
 
         (x, new_cache), _ = self._scan(
-            body, (x, cache_seg), (p_seg, jnp.arange(seg.n)))
+            body, (x, cache_seg),
+            (None if stream else p_seg, jnp.arange(seg.n)))
         return x, new_cache
 
     def _run_vlm_segment(self, seg, p_seg, x, cache_seg, cross_src, kw):

@@ -31,9 +31,9 @@ from ..configs.base import ArchConfig
 from . import attention as attn_mod
 from .attention import (KVCache, PagedKVCache, RingKVCache, chunked_attention,
                         decode_attention)
-from .layers import (ParamSpec, apply_mlp, apply_norm, apply_rope, embed,
-                     mlp_schema, norm_schema, pod_dense, unembed,
-                     embed_schema)
+from .layers import (LayerSlice, ParamSpec, apply_mlp, apply_norm,
+                     apply_rope, embed, mlp_schema, norm_schema, pod_dense,
+                     unembed, embed_schema)
 from .moe import apply_moe, moe_schema
 from .ssm import SSMCache, apply_ssm, ssm_schema
 
@@ -456,6 +456,24 @@ def apply_block(p, x, cfg: ArchConfig, kind: str, *,
     else:
         y = apply_mlp(p["mlp"], h, cfg.activation, use_pallas=use_pallas)
     return x + y, new_cache
+
+
+def layer_view(p_seg, i, cfg: ArchConfig):
+    """Layer i of a stacked segment, for the serving scan on the pod GEMM.
+
+    The weights of pod GEMMs stay in their stacks as `LayerSlice`s, and
+    the GEMM streams layer i's blocks from them: the MLP's, and GQA's
+    projections (q/k/v read in their stored [d, H, hd] layout; o's
+    [H, hd, d] folds into [(H hd), d] without moving a byte). Every other
+    leaf is indexed out; XLA fuses a norm scale's slice into its
+    consumer."""
+    def view(path, a):
+        keys = tuple(getattr(k, "key", None) for k in path)
+        if keys[:1] == ("mlp",) or (keys[:1] == ("attn",)
+                                    and cfg.mla is None):
+            return LayerSlice(a, i)
+        return jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False)
+    return jax.tree_util.tree_map_with_path(view, p_seg)
 
 
 def cross_kv_precompute(p_cross, src, cfg: ArchConfig):

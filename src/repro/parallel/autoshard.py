@@ -128,33 +128,65 @@ def choose_plan(cfg: ArchConfig, shape: ShapeConfig, mesh_shape: dict
 # those counts and is lru-cached per (shape, dtype) — the per-shape cache
 # the serving hot loop relies on (one autotune per layer shape, ever).
 
-# MXU peak: one 128x128 MAC wave per cycle; HBM: ~1 KiB/cycle at ~1 GHz
-# (the v4-class ridge of ~16 MACs/byte — only the ratio matters here).
-_MACS_PER_CYCLE = 128 * 128
-_HBM_BYTES_PER_CYCLE = 1024
+# Time in ns on a TPU v5e (published peaks: 197 TFLOP/s bf16, 819 GB/s
+# HBM), a ridge of 120 MACs/byte. The ridge matters: at 16 MACs/byte
+# prefill shapes look compute-bound and win with blocks that re-read the
+# operands from HBM; a [4096, 11008] x [11008, 4096] prefill GEMM took
+# 3.9 ms at such blocks (256, 256, 5504) and 2.3 ms at (512, 1024, 256).
+_MACS_PER_NS = 98_500
+_HBM_BYTES_PER_NS = 819
 _VMEM_BUDGET = 12 * 2 ** 20   # working-set ceiling of the ~16 MiB VMEM
+# Fixed cost of one grid step, whatever its block size (starting DMAs,
+# pipeline bookkeeping): 0.13-0.145 us a step, measured for the pod GEMM
+# at decode shapes on a v5e.
+_STEP_NS = 140
+_FALLBACK_BLOCKS = (128, 256, 512)
 
 
 def _rup8(d: int) -> int:
     return max(8, ((d + 7) // 8) * 8)
 
 
+def block_candidates(d: int, fallback=_FALLBACK_BLOCKS) -> tuple[int, ...]:
+    """Block sizes the autotuner tries along a dimension of size d: every
+    multiple of the 128-wide MXU that divides d, so the dimension is never
+    padded (a small-M stream can take a block as long as K); where none
+    divides d, the fallback sizes, which pad it by less than one block.
+    ops.systolic_gemm clips a block to the sublane-rounded dimension, so
+    a dimension under 128 takes one block of its own size."""
+    return tuple(b for b in range(MXU, d + 1, MXU) if d % b == 0) or \
+        tuple(fallback)
+
+
+def head_blocks(n: int, head_dim: int, dtype_bytes: int,
+                blocks: tuple[int, ...]) -> tuple[int, ...]:
+    """The block_n sizes a weight stored as [K, N // head_dim, head_dim]
+    can stream: whole heads, as many as fill the packed sublane tile of
+    the dtype (16 for bf16), or all of N."""
+    group = head_dim * 8 * 4 // dtype_bytes
+    return tuple(b for b in blocks if b == n or b % group == 0)
+
+
 @functools.lru_cache(maxsize=4096)
 def _choose_blocks_cached(m: int, k: int, n: int,
-                          candidates=(128, 256, 512),
+                          candidates=_FALLBACK_BLOCKS,
                           dtype_bytes: int = 2, out_bytes: int = 4,
-                          vmem_budget: int = _VMEM_BUDGET
+                          vmem_budget: int = _VMEM_BUDGET,
+                          head_dim: int = 0
                           ) -> tuple[int, int, int]:
     """The cached autotuner body behind `choose_blocks` (which adds the
     obs telemetry: cache hit/miss counters + per-shape utilization)."""
-    # selection key: roofline time, then HBM traffic (a compute-bound tie
+    # selection key: modelled time, then HBM traffic (a compute-bound tie
     # must not pick the max-traffic geometry), then VMEM footprint
     best, best_key = (MXU, MXU, MXU), (float("inf"),) * 3
     seen_eff: set[tuple[int, int, int]] = set()
     spec = [GemmSpec(d1=m, d2=k, d3=n)]
-    for bm in candidates:
-        for bn in candidates:
-            for bk in candidates:
+    n_blocks = block_candidates(n, candidates)
+    if head_dim:
+        n_blocks = head_blocks(n, head_dim, dtype_bytes, n_blocks) or n_blocks
+    for bm in block_candidates(m, candidates):
+        for bn in n_blocks:
+            for bk in block_candidates(k, candidates):
                 # kernel-effective blocks (ops.systolic_gemm clips the same
                 # way: min(block, sublane-rounded dim))
                 bm_e = min(bm, _rup8(m))
@@ -163,10 +195,13 @@ def _choose_blocks_cached(m: int, k: int, n: int,
                 if (bm_e, bn_e, bk_e) in seen_eff:
                     continue
                 seen_eff.add((bm_e, bn_e, bk_e))
-                # VMEM working set: double-buffered streaming blocks + the
-                # f32/int32 accumulator scratch + the output block
+                # VMEM working set: double-buffered x/w and output blocks,
+                # the 32-bit accumulator scratch, and the 32-bit temporaries
+                # of one block (the dot's result, the epilogue's math)
                 vmem = (2 * (bm_e * bk_e + bk_e * bn_e) * dtype_bytes
-                        + bm_e * bn_e * (4 + out_bytes))
+                        + bm_e * bn_e * (4 + 8 + 2 * out_bytes))
+                if head_dim:                # the w block, folded in VMEM
+                    vmem += bk_e * bn_e * dtype_bytes
                 if vmem > vmem_budget:
                     continue
                 st = tile_stats(spec, ArrayConfig(rows=bk_e, cols=bn_e),
@@ -177,11 +212,15 @@ def _choose_blocks_cached(m: int, k: int, n: int,
                 # HBM traffic of the kernel's K-minor grid walk: every
                 # (i, j, l) step streams one x and one w block; outputs
                 # write once per (i, l)
-                traffic = (n_i * n_l * n_j * (bm_e * bk_e + bk_e * bn_e)
-                           * dtype_bytes
-                           + n_i * n_l * bm_e * bn_e * out_bytes)
-                t = max(padded_macs / _MACS_PER_CYCLE,
-                        traffic / _HBM_BYTES_PER_CYCLE)
+                in_block = (bm_e * bk_e + bk_e * bn_e) * dtype_bytes
+                out_block = bm_e * bn_e * out_bytes
+                traffic = n_i * n_l * (n_j * in_block + out_block)
+                # the stream overlaps all but the first blocks in and the
+                # last block out, and every grid step costs a fixed time
+                t = (max(padded_macs / _MACS_PER_NS,
+                         traffic / _HBM_BYTES_PER_NS)
+                     + (in_block + out_block) / _HBM_BYTES_PER_NS
+                     + n_i * n_j * n_l * _STEP_NS)
                 key = (t, traffic, vmem)
                 if key < best_key:
                     best, best_key = (bm, bn, bk), key
@@ -207,20 +246,28 @@ def tile_utilization(m: int, k: int, n: int,
 
 
 def choose_blocks(m: int, k: int, n: int,
-                  candidates=(128, 256, 512),
+                  candidates=_FALLBACK_BLOCKS,
                   dtype_bytes: int = 2, out_bytes: int = 4,
-                  vmem_budget: int = _VMEM_BUDGET) -> tuple[int, int, int]:
+                  vmem_budget: int = _VMEM_BUDGET,
+                  head_dim: int = 0) -> tuple[int, int, int]:
     """Pallas GEMM block sizes for an (m x k) @ (k x n) GEMM, chosen by the
     SOSA DSE cost model (see kernels/systolic_gemm/systolic_gemm.py for the
     full autotuner contract).
 
-    For each candidate (bm, bn, bk) the kernel-effective geometry (blocks
-    clipped to the padded problem, exactly as ops.systolic_gemm clips) is
-    scored as a roofline: max(padded-MAC compute time, HBM stream time)
-    over `tile_stats`' closed-form grid counts, subject to the VMEM budget
-    (double-buffered x/w blocks + accumulator + output block). Returns the
-    best (block_m, block_n, block_k); results are lru-cached per shape
+    For each candidate (bm, bn, bk) — per dimension the 128-multiples that
+    divide it, or `candidates` where none does (`block_candidates`) — the
+    kernel-effective geometry (blocks clipped to the padded problem,
+    exactly as ops.systolic_gemm clips) is scored as a roofline: max(
+    padded-MAC compute time, HBM stream time) over `tile_stats`' closed-
+    form grid counts, plus the first blocks in and last block out, which
+    the stream cannot overlap, and `_STEP_NS` per grid step, subject
+    to the VMEM budget (double-buffered x/w and output blocks, the
+    accumulator, one block of 32-bit temporaries). Returns the best
+    (block_m, block_n, block_k); results are lru-cached per shape
     (`choose_blocks.cache_info()` / `.cache_clear()` reach the cache).
+    `head_dim` > 0 describes a weight stored as [K, N // head_dim,
+    head_dim]: block_n then spans whole groups of heads (`head_blocks`),
+    and the kernel's VMEM holds one more w block, folded to [bk, bn].
 
     Every call records telemetry into the process-global obs registry
     (obs.metrics.registry): an `autotune.cache{result=hit|miss}` counter,
@@ -232,7 +279,8 @@ def choose_blocks(m: int, k: int, n: int,
     """
     before = _choose_blocks_cached.cache_info().misses
     blocks = _choose_blocks_cached(
-        m, k, n, tuple(candidates), dtype_bytes, out_bytes, vmem_budget)
+        m, k, n, tuple(candidates), dtype_bytes, out_bytes, vmem_budget,
+        head_dim)
     hit = _choose_blocks_cached.cache_info().misses == before
     from ..obs.metrics import registry
     reg = registry()
@@ -253,7 +301,7 @@ choose_blocks.cache_clear = _choose_blocks_cached.cache_clear
 
 @functools.lru_cache(maxsize=4096)
 def choose_blocks_grouped(g: int, m: int, k: int, n: int,
-                          candidates=(128, 256, 512),
+                          candidates=_FALLBACK_BLOCKS,
                           dtype_bytes: int = 2, out_bytes: int = 4,
                           vmem_budget: int = _VMEM_BUDGET
                           ) -> tuple[int, int, int]:

@@ -12,10 +12,8 @@ try:
 except ModuleNotFoundError:  # degrade gracefully: deterministic fallback
     from _hypothesis_fallback import given, settings, st
 
-from repro.parallel.autoshard import (_VMEM_BUDGET, _rup8, choose_blocks,
-                                      choose_blocks_grouped)
-
-CANDIDATES = (128, 256, 512)
+from repro.parallel.autoshard import (_VMEM_BUDGET, _rup8, block_candidates,
+                                      choose_blocks, choose_blocks_grouped)
 
 
 def _ops_effective(blocks, m, k, n):
@@ -26,7 +24,10 @@ def _ops_effective(blocks, m, k, n):
 
 
 def _check_contract(blocks, m, k, n, dtype_bytes, out_bytes):
-    assert all(b in CANDIDATES for b in blocks)
+    # each block is a 128-multiple dividing its dimension, or where none
+    # divides it, one of the fallback sizes (128, 256, 512)
+    for dim, blk in zip((m, n, k), blocks):
+        assert blk in block_candidates(dim)
     bm_e, bn_e, bk_e = _ops_effective(blocks, m, k, n)
     # the padded problem ops.py builds is an exact multiple of the
     # effective blocks (the kernel asserts this; here it's a property)
@@ -82,3 +83,73 @@ def test_choose_blocks_grouped_moe_shapes(g, cap, d, f):
 def test_choose_blocks_grouped_rejects_zero_groups():
     with pytest.raises(AssertionError):
         choose_blocks_grouped(0, 8, 64, 64)
+
+
+def _grid_steps(blocks, m, k, n):
+    bm_e, bn_e, bk_e = _ops_effective(blocks, m, k, n)
+    return -(-m // bm_e) * -(-n // bn_e) * -(-k // bk_e)
+
+
+# yi-6b's decode projections at 8 lanes: gate/up, down, q/o, k/v
+YI_DECODE_LAYER = [(8, 4096, 11008), (8, 11008, 4096), (8, 4096, 4096),
+                   (8, 4096, 512)]
+
+
+@pytest.mark.parametrize("mkn", YI_DECODE_LAYER,
+                         ids=["gate-up", "down", "q-o", "k-v"])
+def test_choose_blocks_small_m_takes_few_large_blocks(mkn):
+    """A small-M stream is bound by its weight's bytes: with a per-step
+    cost in the score the autotuner takes blocks of megabytes, at most 64
+    grid steps a projection (1,376 for gate/up with the 128-512 blocks)."""
+    m, k, n = mkn
+    blocks = choose_blocks(m, k, n, dtype_bytes=2, out_bytes=2)
+    _check_contract(blocks, m, k, n, 2, 2)
+    assert _grid_steps(blocks, m, k, n) <= 64
+    assert n % blocks[1] == 0 and k % blocks[2] == 0     # stack never padded
+
+
+def test_choose_blocks_lm_head_takes_the_fewest_steps_vmem_allows():
+    """yi-6b's LM head ([8, 4096] x [4096, 64000], 524 MB of weight) cannot
+    fit 64 steps in the 12 MiB budget: that would take 8 MB blocks, 16 MB
+    double-buffered. It takes the fewest steps of any geometry that fits
+    (100), against 4,000 with the 128-512 blocks."""
+    m, k, n = 8, 4096, 64000
+    blocks = choose_blocks(m, k, n, dtype_bytes=2, out_bytes=2)
+    _check_contract(blocks, m, k, n, 2, 2)
+    fewest = min(
+        _grid_steps((8, bn, bk), m, k, n)
+        for bn in block_candidates(n) for bk in block_candidates(k)
+        if 2 * (8 * bk + bk * bn) * 2 + 8 * bn * 16 <= _VMEM_BUDGET)
+    assert _grid_steps(blocks, m, k, n) == fewest == 100
+
+
+@settings(max_examples=30, deadline=None)
+@given(m=st.integers(1, 4096), k=st.integers(1, 96), n=st.integers(1, 96),
+       out_bytes=st.sampled_from([2, 4]))
+def test_choose_blocks_never_pads_a_dimension_with_a_dividing_block(
+        m, k, n, out_bytes):
+    """A dimension with a 128-multiple divisor is never padded: M, K and
+    N drawn as 128 x (1..96), and M also free (decode lanes)."""
+    k, n = 128 * k, 128 * n
+    blocks = choose_blocks(m, k, n, dtype_bytes=2, out_bytes=out_bytes)
+    _check_contract(blocks, m, k, n, 2, out_bytes)
+    bm_e, bn_e, bk_e = _ops_effective(blocks, m, k, n)
+    assert k % bk_e == 0 and n % bn_e == 0
+    if m % 128 == 0:
+        assert m % bm_e == 0
+
+
+@settings(max_examples=20, deadline=None)
+@given(m=st.sampled_from([1, 8, 64, 512, 4096, 8192]),
+       k=st.sampled_from([64, 1024, 4096, 5120, 11008, 14336, 28672]),
+       n=st.sampled_from([512, 4096, 11008, 50280, 64000, 128256]),
+       dtype_bytes=st.sampled_from([1, 2, 4]),
+       out_bytes=st.sampled_from([2, 4]))
+def test_choose_blocks_contract_over_the_extended_candidates(
+        m, k, n, dtype_bytes, out_bytes):
+    """The contract (budget, no padding by a full block, sizes from the
+    dimension's candidates) over model widths whose divisors reach far
+    beyond 512."""
+    blocks = choose_blocks(m, k, n, dtype_bytes=dtype_bytes,
+                           out_bytes=out_bytes)
+    _check_contract(blocks, m, k, n, dtype_bytes, out_bytes)

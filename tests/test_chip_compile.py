@@ -13,7 +13,9 @@ never happen while a module is imported.
 
 from __future__ import annotations
 
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -91,3 +93,106 @@ def test_grouped_gemm_compiles_for_v5e_dbrx_experts(one_chip):
     assert "tpu_custom_call" in _compiled_text(
         grouped_gemm, [(16, 128, 6144), (16, 6144, 10752)], one_chip,
         activation="silu")
+
+
+@pytest.mark.parametrize("mkn", [GEMM_CASES["yi6b-decode-up"],
+                                 GEMM_CASES["yi6b-decode-down"],
+                                 GEMM_CASES["yi6b-prefill-up"]],
+                         ids=["decode-up", "decode-down", "prefill-up"])
+def test_stacked_systolic_gemm_compiles_for_v5e(one_chip, mkn):
+    """The layer index as a scalar-prefetch operand: the kernel takes the
+    whole [32, K, N] stack, and nothing slices it."""
+    m, k, n = mkn
+    args = [jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
+            for s in ((m, k), (32, k, n))]
+    i = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    text = jax.jit(lambda x, w, i: systolic_gemm(
+        x, w, layer=i, interpret=False, out_dtype=jnp.bfloat16)).lower(
+        *args, i).compile().as_text()
+    assert "tpu_custom_call" in text and "dynamic-slice" not in text
+
+
+_INST = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = (\(.*?\)|\S+) "
+                   r"([a-z][a-z\-]*)\((.*)$")
+
+
+def _readers(text: str, stacks) -> dict:
+    """(op kind, custom-call target) -> count, over the instructions of a
+    compiled HLO module that take an array of a shape in `stacks`."""
+    shape_of, seen = {}, {}
+    for line in text.splitlines():
+        m = _INST.match(line)
+        if not m:
+            continue
+        name, result, kind, rest = m.groups()
+        dims = re.match(r"[a-z0-9]+\[([0-9,]*)\]", result)
+        if dims:
+            shape_of[name] = tuple(int(d) for d in dims.group(1).split(",")
+                                   if d)
+        if kind in ("parameter", "get-tuple-element", "tuple", "while",
+                    "bitcast"):
+            continue
+        operands = re.findall(r"%([\w.\-]+)", rest.split("), ")[0])
+        if any(shape_of.get(o) in stacks for o in operands):
+            target = re.search(r'custom_call_target="([^"]+)"', rest)
+            key = (kind, target.group(1) if target else "")
+            seen[key] = seen.get(key, 0) + 1
+    return seen
+
+
+def test_served_decode_chunk_streams_stacked_weights(one_chip, monkeypatch):
+    """The engine's decode chunk of a small yi-6b-shaped model compiled for
+    the chip: every projection's stack (the MLP's; q/k/v in their stored
+    [d, H, hd] layout; o, and k/v of one head, through a bitcast)
+    reaches only the pod GEMM's custom calls, and nothing slices, copies
+    or relayouts a stack.
+
+    Widths are multiples of 128 (2 layers, d 256, d_ff 512, 2 heads / 1
+    KV head of 128) because the chip stores an array whose minor dimension
+    is narrower in another layout (tiny-dense's [2, 128, 64] down stack is
+    stored {1,2,0}), which a kernel reading row-major blocks would have to
+    copy. The compiler may still prefetch a stack this small whole into
+    fast memory (copy-start), which a full-width stack never fits."""
+    import dataclasses
+
+    import repro.kernels.systolic_gemm.ops as ops
+    from repro.configs import get_arch, reduced
+    from repro.models.model import Model
+    from repro.serve.engine import ServeEngine
+    jax.clear_caches()                    # no CPU-traced kernel reused
+    monkeypatch.setattr(ops, "interpret_mode", lambda: False)
+    cfg = dataclasses.replace(reduced(get_arch("yi-6b")), d_model=256,
+                              d_ff=512, n_heads=2, n_kv_heads=1,
+                              head_dim=128, vocab=512)
+    L, d, ff, hd = cfg.n_layers, cfg.d_model, cfg.d_ff, cfg.resolved_head_dim
+    model = Model(cfg, use_pallas=True)
+    pshapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    engine = ServeEngine(model, pshapes, slots=3, max_len=64, decode_chunk=8)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    lanes = jax.ShapeDtypeStruct((3,), jnp.int32, sharding=one_chip)
+    text = engine._decode_fn.lower(
+        on_chip(pshapes), on_chip(engine.cache), lanes, lanes, lanes,
+        jax.ShapeDtypeStruct((3,), jnp.bool_, sharding=one_chip),
+        n=8).compile().as_text()
+    jax.clear_caches()
+    stacks = {(L, d, ff), (L, ff, d), (L, cfg.n_heads, hd, d),
+              (L, cfg.n_heads * hd, d), (L, d, cfg.n_heads, hd),
+              (L, d, cfg.n_kv_heads, hd), (L, d, cfg.n_kv_heads * hd)}
+    readers = _readers(text, stacks)
+    readers.pop(("copy-start", ""), None)
+    # gate, up, down, q, k, v, o
+    assert readers == {("custom-call", "tpu_custom_call"): 7}
+    # no op makes a stack-sized array (a copy or relayout of a stack)
+    sizes = {L * d * ff, L * d * d, L * d * cfg.n_kv_heads * hd}
+    made = [m.group(2) for m in map(_INST.match, text.splitlines())
+            if m and m.group(3) in ("copy", "transpose", "fusion")]
+    for result in made:
+        dims = re.match(r"[a-z0-9]+\[([0-9,]*)\]", result)
+        if dims:
+            shape = [int(x) for x in dims.group(1).split(",") if x]
+            assert not (shape[:1] == [L] and math.prod(shape) in sizes), \
+                result

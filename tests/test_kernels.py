@@ -90,6 +90,90 @@ def test_systolic_gemm_property(m, k, n):
 
 
 # --------------------------------------------------------------------------
+# stacked weights: the layer's blocks read straight from [L, K, N]
+# --------------------------------------------------------------------------
+
+@pytest.mark.parametrize("dtype", ["int8", "bfloat16"])
+def test_systolic_gemm_stacked_layer_matches_slice(dtype):
+    """`layer=i` on a stack gives what the slice w[i] gives, bit for bit,
+    at the same blocks, for every layer."""
+    L, M, K, N = 3, 8, 256, 384
+    if dtype == "int8":
+        x = jnp.asarray(RNG.integers(-100, 100, (M, K)), jnp.int8)
+        w = jnp.asarray(RNG.integers(-100, 100, (L, K, N)), jnp.int8)
+    else:
+        x = jnp.asarray(RNG.standard_normal((M, K)), jnp.bfloat16)
+        w = jnp.asarray(RNG.standard_normal((L, K, N)), jnp.bfloat16)
+    blocks = dict(block_m=8, block_n=128, block_k=128)
+    for i in range(L):
+        out = systolic_gemm(x, w, layer=jnp.int32(i), activation="silu",
+                            interpret=True, **blocks)
+        ref = systolic_gemm(x, w[i], activation="silu", interpret=True,
+                            **blocks)
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+
+
+@pytest.mark.parametrize("heads,hd", [(16, 128), (4, 128), (4, 16)],
+                         ids=["16x128", "4x128", "4x16-per-layer"])
+def test_systolic_gemm_stacked_heads_read_in_stored_layout(heads, hd):
+    """A stack [L, K, H, hd] (q/k/v as stored) gives what the layer's
+    [K, H * hd] reshape gives, bit for bit at the same blocks. Heads
+    narrower than the 128 lanes take the per-layer path."""
+    from repro.parallel.autoshard import choose_blocks
+    L, M, K = 2, 8, 256
+    x = jnp.asarray(RNG.standard_normal((M, K)), jnp.bfloat16)
+    w = jnp.asarray(RNG.standard_normal((L, K, heads, hd)), jnp.bfloat16)
+    bm, bn, bk = choose_blocks(M, K, heads * hd, out_bytes=2, head_dim=hd)
+    for i in range(L):
+        out = systolic_gemm(x, w, layer=jnp.int32(i), out_dtype=jnp.bfloat16,
+                            interpret=True)
+        ref = systolic_gemm(x, w[i].reshape(K, -1), out_dtype=jnp.bfloat16,
+                            block_m=bm, block_n=bn, block_k=bk,
+                            interpret=True)
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+
+
+def test_systolic_gemm_stacked_without_dividing_blocks_slices_the_layer():
+    """K = 300 has no block that divides it (the autotuner pads it), so the
+    stacked call takes the per-layer path: the layer is sliced out, padded
+    and multiplied, and the result still matches."""
+    L, M, K, N = 2, 8, 300, 256
+    x = jnp.asarray(RNG.standard_normal((M, K)), jnp.float32)
+    w = jnp.asarray(RNG.standard_normal((L, K, N)), jnp.float32)
+    lowered = jax.jit(lambda x, w, i: systolic_gemm(
+        x, w, layer=i, interpret=True)).lower(x, w, jnp.int32(1)).as_text()
+    assert "dynamic_slice" in lowered
+    for i in range(L):
+        out = systolic_gemm(x, w, layer=jnp.int32(i), interpret=True)
+        np.testing.assert_array_equal(
+            np.asarray(out), np.asarray(systolic_gemm(x, w[i],
+                                                      interpret=True)))
+        np.testing.assert_allclose(np.asarray(out),
+                                   np.asarray(systolic_gemm_ref(x, w[i])),
+                                   rtol=1e-5, atol=1e-4)
+
+
+def test_systolic_gemm_stacked_under_scan_with_traced_layer():
+    """The scalar-prefetch index works as a scan's traced loop index: one
+    call of the scan body reads every layer in turn."""
+    L, M, K, N = 4, 16, 128, 256
+    x = jnp.asarray(RNG.standard_normal((M, K)), jnp.float32)
+    w = jnp.asarray(RNG.standard_normal((L, K, N)), jnp.float32)
+
+    def body(h, i):
+        y = fused_lane_gemm(h[None], w, layer=i, interpret=True,
+                            block_m=16, block_n=128, block_k=128)[0]
+        return h, y
+
+    _, ys = jax.jit(lambda x: jax.lax.scan(body, x, jnp.arange(L)))(x)
+    for i in range(L):
+        np.testing.assert_array_equal(
+            np.asarray(ys[i]),
+            np.asarray(systolic_gemm(x, w[i], interpret=True, block_m=16,
+                                     block_n=128, block_k=128)))
+
+
+# --------------------------------------------------------------------------
 # grouped / fused-lane GEMM variants
 # --------------------------------------------------------------------------
 
