@@ -15,7 +15,13 @@ schedule still holds the same work.
 Open loop (`"loop": "open"`): the first round(rate * seconds) requests of
 the base order, due at the partial sums of their gaps, rescaled so that
 the gaps add up to the window: the mean rate is exactly the mix's rate.
-Arrival process: `poisson` (exponential gaps).
+Arrival processes, each drawing gaps of mean 1 before the rescaling:
+
+- `poisson`: exponential gaps (coefficient of variation 1).
+- `gamma` with `"cv": c`: gamma gaps of shape 1/c**2 and scale c**2, so
+  their coefficient of variation is c. c > 1 gives bursts: BurstGPT
+  (arXiv:2401.17644) fits the gaps of served LLM traffic with a gamma
+  distribution of CV well above 1, where a Poisson process has 1.
 
 Length distributions: `lognormal` (median, sigma) and `uniform` (min..max),
 each clipped to [min, max] and rounded to whole tokens.
@@ -52,6 +58,9 @@ def _lengths(rng, d: dict, n: int) -> np.ndarray:
 def _gaps(rng, a: dict, n: int) -> np.ndarray:
     if a["process"] == "poisson":
         return rng.exponential(1.0, n)
+    if a["process"] == "gamma":
+        shape = 1.0 / a["cv"] ** 2
+        return rng.gamma(shape, 1.0 / shape, n)
     raise ValueError(f"unknown arrival process {a['process']!r}")
 
 

@@ -66,19 +66,41 @@ def _get(d: dict, dotted: str):
     return d
 
 
+def _tuples(v):
+    """A JSON value with its lists made tuples (and its objects copied)."""
+    if isinstance(v, dict):
+        return {k: _tuples(x) for k, x in v.items()}
+    return tuple(_tuples(x) for x in v) if isinstance(v, list) else v
+
+
+def _put(kw: dict, dotted: str, value) -> None:
+    *group, leaf = dotted.split(".")
+    for g in group:
+        kw = kw.setdefault(g, {})
+    kw[leaf] = _tuples(value)
+
+
 def arch_config(conf: dict):
     """The program's ArchConfig, derived from the file's published keys:
-    `program_keys` maps an ArchConfig field (dotted for a nested group) to
-    the published key that fixes it; `program_fixed` holds the fields that
-    no published key gives, such as the family."""
-    from repro.configs.base import ArchConfig
-    kw = {"name": conf["name"], **conf.get("program_fixed", {})}
+    `program_keys` maps an ArchConfig field to the published key that
+    fixes it; `program_fixed` holds the fields that no published key
+    gives, such as the family. A field of a nested group is dotted in
+    either (`ssm.d_state`); the groups `ssm`, `moe` and `mla` become
+    SSMConfig, MoEConfig and MLAConfig. JSON lists become tuples."""
+    from repro.configs.base import ArchConfig, MLAConfig, MoEConfig, \
+        SSMConfig
+    groups = {"ssm": SSMConfig, "moe": MoEConfig, "mla": MLAConfig}
+    kw = {"name": conf["name"]}
+    for field, value in conf.get("program_fixed", {}).items():
+        _put(kw, field, value)
     for field, key in conf["program_keys"].items():
-        *group, leaf = field.split(".")
-        d = kw
-        for g in group:
-            d = d.setdefault(g, {})
-        d[leaf] = _get(conf, key)
+        _put(kw, field, _get(conf, key))
+    for field, value in kw.items():
+        if isinstance(value, dict):
+            if field not in groups:
+                raise ValueError(f"{conf['name']}: {field!r} is no nested "
+                                 f"group of ArchConfig")
+            kw[field] = groups[field](**value)
     return ArchConfig(**kw)
 
 

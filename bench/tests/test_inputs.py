@@ -82,3 +82,25 @@ def test_unknown_leaf_has_no_rule():
               "mystery": jax.ShapeDtypeStruct((4, 4), jnp.bfloat16)}
     with pytest.raises(KeyError):
         weights.draw(shapes, 1)
+
+
+def test_gamma_arrivals_keep_cv_and_rate():
+    a = {"process": "gamma", "cv": 3.0, "rate_per_s": 2.5}
+    gaps = loadgen._gaps(np.random.default_rng(5), a, 4096)
+    assert 2.5 <= gaps.std() / gaps.mean() <= 3.5
+    mix = dict(cells.load_json("traffic", "yi6b_chat"), arrivals=a)
+    due = np.array([s.due for s in loadgen.open_loop(mix, 2 ** 40 + 1, 40.0)])
+    # the mean rate over the window is exactly the mix's rate
+    assert len(due) / 40.0 == 2.5
+    assert due[0] == 0.0 and (np.diff(due) >= 0).all() and due[-1] < 40.0
+    # bursts: a gamma schedule of CV 3 packs more requests into its
+    # busiest second than a Poisson one at the same rate
+    poisson = dict(mix, arrivals=dict(a, process="poisson"))
+    busiest = lambda m: np.bincount(np.floor(np.array(
+        [s.due for s in loadgen.open_loop(m, 7, 40.0)])).astype(int)).max()
+    assert busiest(mix) > busiest(poisson)
+
+
+def test_unknown_arrival_process_fails():
+    with pytest.raises(ValueError):
+        loadgen._gaps(np.random.default_rng(0), {"process": "weibull"}, 8)
