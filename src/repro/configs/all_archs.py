@@ -107,13 +107,15 @@ def llama_32_vision_90b() -> ArchConfig:
 
 @register("mamba2-370m")
 def mamba2_370m() -> ArchConfig:
-    # [arXiv:2405.21060; unverified] SSD: 48L d=1024 attn-free,
-    # ssm_state=128, vocab=50280.
+    # [arXiv:2405.21060; hf:state-spaces/mamba2-370m] SSD: 48L d=1024
+    # attn-free, ssm_state=128; vocab_size 50277 padded to a multiple of
+    # pad_vocab_size_multiple 16 (MambaLMHeadModel) = 50288 embedding
+    # rows; residual_in_fp32 as published.
     return ArchConfig(
         name="mamba2-370m", family="ssm",
         n_layers=48, d_model=1024, n_heads=0, n_kv_heads=0,
-        d_ff=0, vocab=50280, activation="silu", use_rope=False,
-        tie_embeddings=True,
+        d_ff=0, vocab=50288, activation="silu", use_rope=False,
+        tie_embeddings=True, residual_in_fp32=True,
         ssm=SSMConfig(d_state=128, head_dim=64, expand=2,
                       conv_kernel=4, chunk_size=256),
     )

@@ -90,6 +90,9 @@ class ArchConfig:
     # full attention (no sub-quadratic path) — long_500k is skipped if True
     # (SSM / hybrid / sliding-window archs override)
     dtype: str = "bfloat16"
+    # the residual stream between blocks in float32 (mamba_ssm's
+    # `residual_in_fp32`); each block's pre-norm hands its mixer `dtype`
+    residual_in_fp32: bool = False
 
     @property
     def resolved_head_dim(self) -> int:
@@ -219,6 +222,7 @@ def reduced(cfg: ArchConfig) -> ArchConfig:
         norm=cfg.norm,
         use_rope=cfg.use_rope,
         tie_embeddings=cfg.tie_embeddings,
+        residual_in_fp32=cfg.residual_in_fp32,
     )
     if cfg.moe:
         kw["moe"] = MoEConfig(

@@ -1,6 +1,10 @@
 """jit'd public wrappers for the systolic GEMM kernels: pad to block
 multiples, dispatch to Pallas (Mosaic on TPU, interpret mode on the CPU
-backend: kernels/backend.py), slice back.
+backend: kernels/backend.py), slice back. A weight is never padded along
+N under a lane-aligned block: the last block overhangs N and the kernel
+discards what lies past it (`_overhangs`), so a weight such as
+mamba2's [1024, 4384] in_proj or a [50288, 1024] tied embedding is read
+in place.
 
 Block geometry defaults to the DSE autotuner
 (parallel.autoshard.choose_blocks — tile_stats-driven, VMEM-budget-aware,
@@ -47,14 +51,22 @@ def _auto_blocks(m: int, k: int, n: int, dtype, out_dtype, head_dim: int = 0
                          head_dim=head_dim)
 
 
+def _overhangs(n: int, bn: int) -> bool:
+    """Whether the kernel's last block along N may overhang N, so that w
+    is read in place instead of padded: a lane-aligned block does (the
+    kernel discards the out-of-range columns), a narrower one pads N."""
+    return bn % 128 == 0 or n % bn == 0
+
+
 def _streams(w, n: int, blocks: tuple[int, int, int]) -> bool:
     """Whether the kernel can read a stack's layer in place at these
-    (clipped) blocks: they divide K and N (a stack is never padded), and
-    a stack stored as [L, K, H, D] has lane-wide heads and blocks of
-    whole groups of heads (autoshard.head_blocks)."""
+    (clipped) blocks: they divide K (a stack is never padded) and divide
+    N or overhang it (`_overhangs`), and a stack stored as [L, K, H, D]
+    has lane-wide heads and blocks of whole groups of heads
+    (autoshard.head_blocks)."""
     from ...parallel.autoshard import head_blocks
     _, bn, bk = blocks
-    if w.shape[1] % bk or n % bn:
+    if w.shape[1] % bk or not _overhangs(n, bn):
         return False
     if w.ndim == 3:
         return True
@@ -114,13 +126,14 @@ def systolic_gemm(x, w, scale=None, bias=None, *, layer=None,
         w = w.reshape(K, N)
         layer = None
     xp = _pad_to(_pad_to(x, bm, 0), bk, 1)
-    wp = w if layer is not None else _pad_to(_pad_to(w, bk, 0), bn, 1)
+    pn = 1 if _overhangs(N, bn) else bn
+    wp = w if layer is not None else _pad_to(_pad_to(w, bk, 0), pn, 1)
     if scale is None:
         scale = jnp.ones((N,), jnp.float32)
     if bias is None:
         bias = jnp.zeros((N,), jnp.float32)
-    sp = _pad_to(scale, bn, 0)
-    bp = _pad_to(bias, bn, 0)
+    sp = _pad_to(scale, pn, 0)
+    bp = _pad_to(bias, pn, 0)
     out = systolic_gemm_pallas(
         xp, wp, sp, bp, layer=layer, block_m=bm, block_n=bn, block_k=bk,
         activation=activation, out_dtype=out_dtype, interpret=interpret)
@@ -192,13 +205,14 @@ def systolic_gemm_t(x, w, scale=None, bias=None, *, activation=None,
     bm, bn, bk = (min(block_m, _rup(M)), min(block_n, _rup(N)),
                   min(block_k, _rup(K)))
     xp = _pad_to(_pad_to(x, bm, 0), bk, 1)
-    wp = _pad_to(_pad_to(w, bn, 0), bk, 1)
+    pn = 1 if _overhangs(N, bn) else bn
+    wp = _pad_to(_pad_to(w, pn, 0), bk, 1)
     if scale is None:
         scale = jnp.ones((N,), jnp.float32)
     if bias is None:
         bias = jnp.zeros((N,), jnp.float32)
-    sp = _pad_to(scale, bn, 0)
-    bp = _pad_to(bias, bn, 0)
+    sp = _pad_to(scale, pn, 0)
+    bp = _pad_to(bias, pn, 0)
     out = systolic_gemm_nt_pallas(
         xp, wp, sp, bp, block_m=bm, block_n=bn, block_k=bk,
         activation=activation, out_dtype=out_dtype, interpret=interpret)

@@ -150,6 +150,11 @@ def systolic_gemm_pallas(
 ) -> jax.Array:
     """out = epilogue((x @ w) * scale + bias).
 
+    N need not be a multiple of block_n: the last block along N then
+    overhangs it, its columns past N are garbage on the way in and are
+    dropped on the way out, and every column that is kept contracts
+    only in-range data (K is whole blocks).
+
     With `layer`, w is a stack of layers and the kernel reads layer
     `layer`'s blocks straight from it: the index is a scalar-prefetch
     operand that the w BlockSpec's index_map puts on the (squeezed)
@@ -162,10 +167,10 @@ def systolic_gemm_pallas(
     K2 = w.shape[0 if layer is None else 1]
     N = math.prod(w.shape[1 if layer is None else 2:])
     assert K == K2 and w.ndim in ((2,) if layer is None else (3, 4))
-    assert M % block_m == 0 and N % block_n == 0 and K % block_k == 0, (
-        "caller (ops.py) pads to block multiples")
+    assert M % block_m == 0 and K % block_k == 0, (
+        "caller (ops.py) pads M and K to block multiples")
     n_k = K // block_k
-    grid = (M // block_m, N // block_n, n_k)
+    grid = (M // block_m, pl.cdiv(N, block_n), n_k)
 
     kernel = functools.partial(
         _gemm_kernel, n_k=n_k, activation=activation, out_dtype=out_dtype)
@@ -318,15 +323,16 @@ def systolic_gemm_nt_pallas(
     interpret: bool = False,
 ) -> jax.Array:
     """out = epilogue((x @ w.T) * scale + bias) with w in [N, K] layout.
-    Same K-minor psum-chain grid as `systolic_gemm_pallas`; only the w
-    BlockSpec walks (j, k) instead of (k, j)."""
+    Same K-minor psum-chain grid as `systolic_gemm_pallas`, the last
+    block along N overhanging N as there; only the w BlockSpec walks
+    (j, k) instead of (k, j)."""
     M, K = x.shape
     N, K2 = w.shape
     assert K == K2
-    assert M % block_m == 0 and N % block_n == 0 and K % block_k == 0, (
-        "caller (ops.py) pads to block multiples")
+    assert M % block_m == 0 and K % block_k == 0, (
+        "caller (ops.py) pads M and K to block multiples")
     n_k = K // block_k
-    grid = (M // block_m, N // block_n, n_k)
+    grid = (M // block_m, pl.cdiv(N, block_n), n_k)
 
     kernel = functools.partial(
         _gemm_nt_kernel, n_k=n_k, activation=activation, out_dtype=out_dtype)

@@ -11,7 +11,9 @@ process (chip_smoke.py). The run exits 1 if any request was shed for
 non-finite logits.
 
 `--metrics` prints the engine's telemetry snapshot (obs.metrics) after the
-run; `--trace-out PATH` writes the run as Chrome trace-event JSON —
+run, among it `serve.compiles` and `serve.state_bytes{kind=ssm}`, the
+recurrent state the engine allocates (slots x SSMCache.lane_bytes());
+`--trace-out PATH` writes the run as Chrome trace-event JSON —
 drag-and-drop it into ui.perfetto.dev or chrome://tracing. It holds the
 engine's span tree: each step, its admission, the prefill and decode calls
 with their dispatch and host sync, and the host work between them. While

@@ -160,6 +160,8 @@ class Model:
     def _embed_in(self, params, batch, offset=0):
         cfg = self.cfg
         x = embed(params["embed"], batch["tokens"])
+        if cfg.residual_in_fp32:
+            x = x.astype(jnp.float32)
         if not cfg.use_rope and cfg.family != "ssm":
             x = x + _sinusoid(x.shape[1], cfg.d_model,
                               offset=offset).astype(x.dtype)
@@ -340,7 +342,7 @@ class Model:
                                       true_lens=true_lens)
             if cache is not None:
                 new_cache[seg.name] = nc
-        x = apply_norm(params["ln_f"], x, cfg.norm)
+        x = apply_norm(params["ln_f"], x, cfg.norm).astype(cfg.dtype)
         logits = unembed(params["embed"], x, use_pallas=self.use_pallas)
         logits = self.constrain(logits, "logits")
         return logits, (new_cache if cache is not None else None)

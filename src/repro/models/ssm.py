@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ArchConfig
-from .layers import ParamSpec
+from .layers import ParamSpec, pod_dense
 
 
 def ssm_schema(cfg: ArchConfig, layers: int | None = None) -> dict:
@@ -149,18 +149,26 @@ def ssd_reference(x, dt, A, B, C, D, chunk: int):
 
 
 def ssd_decode_step(x, dt, A, B, C, D, h):
-    """One-token recurrence. x [b,H,P]; dt [b,H]; B,C [b,G,N]; h [b,H,P,N]."""
+    """One-token recurrence. x [b,H,P]; dt [b,H]; B,C [b,G,N]; h [b,H,P,N].
+
+    As mamba_ssm's selective_state_update: the state is read in its
+    stored dtype, updated and read out in float32, and stored back in its
+    own dtype, so the bytes moved are those of the stored state."""
     H = x.shape[1]
     G = B.shape[1]
     rep = H // G
-    Bh = jnp.repeat(B, rep, axis=1)   # [b,H,N]
-    Ch = jnp.repeat(C, rep, axis=1)
-    dtf = dt.astype(jnp.float32)
-    dA = jnp.exp(dtf * A[None, :])[..., None, None].astype(h.dtype)
-    h_new = h * dA + jnp.einsum("bH,bHn,bHp->bHpn",
-                                dtf.astype(x.dtype), Bh, x)
-    y = jnp.einsum("bHn,bHpn->bHp", Ch, h_new) + x * D[None, :, None]
-    return y, h_new
+    f32 = jnp.float32
+    Bh = jnp.repeat(B, rep, axis=1).astype(f32)   # [b,H,N]
+    Ch = jnp.repeat(C, rep, axis=1).astype(f32)
+    xf = x.astype(f32)
+    dtf = dt.astype(f32)
+    dA = jnp.exp(dtf * A[None, :])[..., None, None]
+    # elementwise products and a sum, not einsums: a dot on a TPU rounds
+    # float32 operands to bf16 at default precision
+    dBx = (dtf[..., None] * xf)[..., None] * Bh[:, :, None, :]
+    h_new = h.astype(f32) * dA + dBx
+    y = jnp.sum(h_new * Ch[:, :, None, :], axis=-1) + xf * D[None, :, None]
+    return y.astype(x.dtype), h_new.astype(h.dtype)
 
 
 @dataclasses.dataclass
@@ -199,8 +207,11 @@ jax.tree_util.register_dataclass(
 
 
 def apply_ssm(p: dict, u, cfg: ArchConfig, cache: SSMCache | None = None,
-              impl: str = "jnp", true_lens=None):
+              impl: str = "jnp", true_lens=None, use_pallas: bool = False):
     """Full Mamba-2 mixer. u [B,S,D] -> ([B,S,D], new_cache_or_None).
+
+    use_pallas runs in_proj and out_proj on the systolic pod GEMM
+    (layers.pod_dense; either may be a LayerSlice of its stack).
 
     Prefill/train: chunked SSD (cache may be None). When S == 1 and a cache
     is provided, takes the O(1) recurrent path.
@@ -220,7 +231,10 @@ def apply_ssm(p: dict, u, cfg: ArchConfig, cache: SSMCache | None = None,
     P = s.head_dim
     G, N = s.n_groups, s.d_state
 
-    zxbcdt = jnp.einsum("bsd,de->bse", u, p["in_proj"])
+    if use_pallas:
+        zxbcdt = pod_dense(u, p["in_proj"])
+    else:
+        zxbcdt = jnp.einsum("bsd,de->bse", u, p["in_proj"])
     z, x, B, C, dt = _split_proj(zxbcdt, cfg)
     xBC = jnp.concatenate([x, B, C], axis=-1)
     conv_cache = cache.conv if cache is not None else None
@@ -253,6 +267,9 @@ def apply_ssm(p: dict, u, cfg: ArchConfig, cache: SSMCache | None = None,
     yf = (y * jax.nn.silu(z)).astype(jnp.float32)
     var = jnp.mean(yf * yf, axis=-1, keepdims=True)
     y = (yf * jax.lax.rsqrt(var + 1e-6)).astype(u.dtype) * p["norm"]
-    out = jnp.einsum("bse,ed->bsd", y, p["out_proj"])
+    if use_pallas:
+        out = pod_dense(y, p["out_proj"])
+    else:
+        out = jnp.einsum("bse,ed->bsd", y, p["out_proj"])
     new_cache = SSMCache(new_conv, h_new) if cache is not None else None
     return out, new_cache

@@ -364,11 +364,17 @@ def apply_block(p, x, cfg: ArchConfig, kind: str, *,
     cross_src: source embeddings for cross-attention (encoder output /
     image embeddings); at decode the per-layer cross K/V come from the
     cache instead. Returns (x, new_cache_dict).
-    use_pallas: dense/GQA projections, MLPs and the MoE expert dispatch
-    (capacity-bucketed grouped pod GEMM, models/moe.py) run on the
-    systolic pod kernels (MLA, SSM and the cross-attention q/o stay on
-    the reference einsum path)."""
+    use_pallas: dense/GQA projections, the SSM's in/out projections, MLPs
+    and the MoE expert dispatch (capacity-bucketed grouped pod GEMM,
+    models/moe.py) run on the systolic pod kernels (MLA and the
+    cross-attention q/o stay on the reference einsum path)."""
     new_cache: dict = {}
+    act = jnp.dtype(cfg.dtype)
+
+    def norm(name, y):
+        """Pre-norm of the residual stream, in the compute dtype (the
+        stream is float32 under cfg.residual_in_fp32)."""
+        return apply_norm(p[name], y, cfg.norm).astype(act)
 
     def _cross_kv():
         """(k, v) for the cross attention + cache bookkeeping."""
@@ -383,16 +389,17 @@ def apply_block(p, x, cfg: ArchConfig, kind: str, *,
             new_cache["cross"] = CrossKV(k, v)
         return k, v
     if kind == "ssm":
-        h = apply_norm(p["ln_ssm"], x, cfg.norm)
+        h = norm("ln_ssm", x)
         y, sc = apply_ssm(p["ssm"], h, cfg,
                           cache=cache.get("ssm") if cache else None,
-                          impl=ssd_impl, true_lens=true_lens)
+                          impl=ssd_impl, true_lens=true_lens,
+                          use_pallas=use_pallas)
         if sc is not None:
             new_cache["ssm"] = sc
         return x + y, new_cache
 
     if kind == "cross_layer":                    # vlm image layer
-        h = apply_norm(p["ln_cross"], x, cfg.norm)
+        h = norm("ln_cross", x)
         k, v = _cross_kv()
         q = jnp.einsum("bsd,dhk->bshk", h, p["cross"]["q"])
         out = chunked_attention(q, k, v, causal=False)
@@ -400,31 +407,32 @@ def apply_block(p, x, cfg: ArchConfig, kind: str, *,
                        out.reshape(h.shape[0], h.shape[1], cfg.n_heads, -1),
                        p["cross"]["o"])
         x = x + a
-        h = apply_norm(p["ln_mlp"], x, cfg.norm)
+        h = norm("ln_mlp", x)
         return x + apply_mlp(p["mlp"], h, cfg.activation,
                              use_pallas=use_pallas), new_cache
 
     if kind == "hybrid":
-        h = apply_norm(p["ln_attn"], x, cfg.norm)
+        h = norm("ln_attn", x)
         a, ac = apply_gqa(p["attn"], h, cfg, positions=positions,
                           causal=causal, window=window, impl=impl,
                           cache=cache.get("attn") if cache else None,
                           kv_rep=kv_rep, use_pallas=use_pallas,
                           true_lens=true_lens)
-        s, sc = apply_ssm(p["ssm"], apply_norm(p["ln_ssm"], x, cfg.norm),
+        s, sc = apply_ssm(p["ssm"], norm("ln_ssm", x),
                           cfg, cache=cache.get("ssm") if cache else None,
-                          impl=ssd_impl, true_lens=true_lens)
+                          impl=ssd_impl, true_lens=true_lens,
+                          use_pallas=use_pallas)
         if ac is not None:
             new_cache["attn"] = ac
         if sc is not None:
             new_cache["ssm"] = sc
         x = x + 0.5 * (a + s)
-        h = apply_norm(p["ln_mlp"], x, cfg.norm)
+        h = norm("ln_mlp", x)
         return x + apply_mlp(p["mlp"], h, cfg.activation,
                              use_pallas=use_pallas), new_cache
 
     # attention blocks (dense / moe / encoder / crossdec)
-    h = apply_norm(p["ln_attn"], x, cfg.norm)
+    h = norm("ln_attn", x)
     if cfg.mla is not None and kind in ("dense", "moe"):
         a, ac = apply_mla(p["attn"], h, cfg, positions=positions, impl=impl,
                           cache=cache.get("attn") if cache else None,
@@ -440,7 +448,7 @@ def apply_block(p, x, cfg: ArchConfig, kind: str, *,
     x = x + a
 
     if kind == "crossdec":
-        h = apply_norm(p["ln_cross"], x, cfg.norm)
+        h = norm("ln_cross", x)
         k, v = _cross_kv()
         q = jnp.einsum("bsd,dhk->bshk", h, p["cross"]["q"])
         out = chunked_attention(q, k, v, causal=False)
@@ -449,7 +457,7 @@ def apply_block(p, x, cfg: ArchConfig, kind: str, *,
                        p["cross"]["o"])
         x = x + a
 
-    h = apply_norm(p["ln_mlp"], x, cfg.norm)
+    h = norm("ln_mlp", x)
     if kind == "moe":
         y = apply_moe(p["moe"], h, cfg, constrain=constrain,
                       use_pallas=use_pallas)
@@ -462,15 +470,16 @@ def layer_view(p_seg, i, cfg: ArchConfig):
     """Layer i of a stacked segment, for the serving scan on the pod GEMM.
 
     The weights of pod GEMMs stay in their stacks as `LayerSlice`s, and
-    the GEMM streams layer i's blocks from them: the MLP's, and GQA's
+    the GEMM streams layer i's blocks from them: the MLP's, GQA's
     projections (q/k/v read in their stored [d, H, hd] layout; o's
-    [H, hd, d] folds into [(H hd), d] without moving a byte). Every other
-    leaf is indexed out; XLA fuses a norm scale's slice into its
-    consumer."""
+    [H, hd, d] folds into [(H hd), d] without moving a byte) and the
+    SSM's in_proj and out_proj. Every other leaf is indexed out; XLA
+    fuses a norm scale's slice into its consumer."""
     def view(path, a):
         keys = tuple(getattr(k, "key", None) for k in path)
         if keys[:1] == ("mlp",) or (keys[:1] == ("attn",)
-                                    and cfg.mla is None):
+                                    and cfg.mla is None) or \
+                keys[:2] in (("ssm", "in_proj"), ("ssm", "out_proj")):
             return LayerSlice(a, i)
         return jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False)
     return jax.tree_util.tree_map_with_path(view, p_seg)

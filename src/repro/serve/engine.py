@@ -219,8 +219,9 @@ class ServeEngine:
         # tree of every step for the Perfetto export (obs/export.py):
         # step > admit, decode.prep, decode/chunk{n}, decode.retire; the
         # device calls prefill/* (category "prefill") and decode/*
-        # ("decode") with their dispatch and sync; the rest category
-        # "engine". Without such a tracer no span is made and no
+        # ("decode") with their dispatch and sync, each with the
+        # `state_bytes` of recurrent state its lanes hold; the rest
+        # category "engine". Without such a tracer no span is made and no
         # annotation opened.
         self.tracer = tracer
         # optional obs.metrics.MetricsRegistry. Recording is host-side
@@ -254,6 +255,16 @@ class ServeEngine:
                                           kv_pages=kv_pages)
         else:
             self.cache = model.init_cache(slots, max_len, src_len=src_len)
+        # bytes of one lane's recurrent state (SSM conv window and SSD
+        # state, every layer): what a prefill writes and a decode step
+        # reads and writes per lane; 0 for families without such state
+        self.lane_state_bytes = sum(
+            leaf.lane_bytes() for leaf in jax.tree.leaves(
+                self.cache, is_leaf=lambda x: isinstance(x, SSMCache))
+            if isinstance(leaf, SSMCache))
+        if metrics is not None:
+            metrics.counter("serve.state_bytes", kind="ssm").inc(
+                slots * self.lane_state_bytes)
         # in-chunk lane recycling: after the retires of a decode chunk,
         # re-run admission at the SAME host sync so a lane that died
         # mid-chunk hands its slot (and pages) to a queued request with no
@@ -608,7 +619,8 @@ class ServeEngine:
                         tokens=n_tokens, rids=[r.rid for r in reqs],
                         stalled_lanes=sum(r is not None
                                           for r in self.active),
-                        compiled=compiled)
+                        compiled=compiled,
+                        state_bytes=len(reqs) * self.lane_state_bytes)
         if self.tracer is not None:
             for r in reqs:       # successful work only enters the trace
                 self.tracer.on_prefill(r.rid, len(r.prompt),
@@ -774,7 +786,8 @@ class ServeEngine:
                         rids=[req.rid],
                         stalled_lanes=sum(r is not None
                                           for r in self.active),
-                        compiled=compiled)
+                        compiled=compiled,
+                        state_bytes=self.lane_state_bytes)
         if first < 0:
             self._shed_non_finite([(req, slot)], where="prefill")
             return
@@ -999,7 +1012,8 @@ class ServeEngine:
                 sp.done(t_start, t_end, steps=n, lanes=len(live),
                         tokens=int(stats[0]), live_end=int(stats[1]),
                         rids=[self.active[i].rid for i in live],
-                        compiled=compiled)
+                        compiled=compiled,
+                        state_bytes=len(live) * self.lane_state_bytes)
         with self._span("decode.retire"):
             self._retire_chunk(live, n, pos0, seq, emits, stats, t_start,
                                t_end)
@@ -1191,10 +1205,9 @@ class ServeEngine:
             if isinstance(leaf, PagedKVCache):
                 per_tok += (leaf.k.nbytes + leaf.v.nbytes) \
                     // (pool.n_pages * pool.page_size)
-            elif isinstance(leaf, SSMCache):
-                resident += leaf.lane_bytes() * self.slots
             elif isinstance(leaf, RingKVCache):
                 resident += leaf.k.nbytes + leaf.v.nbytes
+        resident += self.slots * self.lane_state_bytes
         live_tokens = sum(int(self.positions[i])
                           for i, r in enumerate(self.active)
                           if r is not None)

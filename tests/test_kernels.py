@@ -153,6 +153,24 @@ def test_systolic_gemm_stacked_without_dividing_blocks_slices_the_layer():
                                    rtol=1e-5, atol=1e-4)
 
 
+@pytest.mark.parametrize("k,n", [(1024, 4384), (2048, 1024)],
+                         ids=["in_proj", "out_proj"])
+def test_systolic_gemm_streams_mamba2_stacks_in_place(k, n):
+    """mamba2-370m's in_proj [L, 1024, 4384] (N no multiple of 128: the
+    last block overhangs N) and out_proj [L, 2048, 1024], read from the
+    stack at the autotuner's blocks, equal jnp.dot of the layer."""
+    from repro.kernels.systolic_gemm.ops import _auto_blocks, _streams
+    L, M = 2, 64
+    x = jnp.asarray(RNG.standard_normal((M, k)), jnp.bfloat16)
+    w = jnp.asarray(RNG.standard_normal((L, k, n)), jnp.bfloat16)
+    assert _streams(w, n, _auto_blocks(M, k, n, x.dtype, jnp.float32))
+    for i in range(L):
+        out = systolic_gemm(x, w, layer=jnp.int32(i), interpret=True)
+        ref = jnp.dot(x, w[i], preferred_element_type=jnp.float32)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   rtol=1e-5, atol=1e-3)
+
+
 def test_systolic_gemm_stacked_under_scan_with_traced_layer():
     """The scalar-prefetch index works as a scan's traced loop index: one
     call of the scan body reads every layer in turn."""
