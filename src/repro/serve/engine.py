@@ -6,13 +6,15 @@ batch. Two optimizations move the hot loop on-device (seed behavior is
 preserved bit-for-bit in serve/reference.py as the oracle):
 
   * **Bucketed prefill** — prompts are right-padded to power-of-two length
-    buckets, and queued requests of the same bucket batch into ONE prefill
-    call over a fixed `slots`-lane batch. The jit cache is therefore
-    bounded by the number of buckets (<= log2(max_len) variants) instead of
-    one entry per distinct prompt length. Padding is inert for
-    attention-only caches: causal masking keeps padded positions out of
-    real positions' math, and a post-prefill length fixup masks the padded
-    cache slots until decode overwrites them. Stateful mixers (SSM, ring
+    buckets, and queued requests of the same bucket batch into prefill
+    calls of a fixed width per bucket: W(b) lanes, as many as ride free on
+    the weight read (see PREFILL_ROWS), never more than `slots`. A larger
+    group splits into several calls. The jit cache is therefore bounded by
+    the number of buckets (<= log2(max_len) variants) instead of one entry
+    per distinct prompt length. Padding is inert for attention-only
+    caches: causal masking keeps padded positions out of real positions'
+    math, and a post-prefill length fixup masks the padded cache slots
+    until decode overwrites them. Stateful mixers (SSM, ring
     buffers) join the bucket path via masked state updates driven by the
     per-lane true lengths (dt-masked SSD recurrence, true-length conv
     window, per-lane ring slot gather — see Model.forward(true_lens=...)).
@@ -85,6 +87,15 @@ from .chaos import (FaultInjector, NumericalFault, PermanentFault,
 from .paging import PagePool
 
 _OFF = contextlib.nullcontext()       # a span while no tracer takes spans
+
+# Token rows a bucketed prefill call carries: a bucket-b call computes
+# W(b) = min(slots, next_pow2(ceil(PREFILL_ROWS / b))) lanes. A bf16
+# projection of M rows does 2MKN FLOPs on 2KN weight bytes, M FLOP a byte;
+# a TPU v5e's ridge is 197 TFLOP/s over 819 GB/s, about 240 FLOP a byte.
+# Below the ridge the weight read bounds the call and extra lanes ride
+# free; above it each lane costs compute in proportion, so a call carries
+# about the ridge's rows and no more. Empty lanes are pure padding.
+PREFILL_ROWS = 256
 
 
 @dataclasses.dataclass
@@ -220,8 +231,9 @@ class ServeEngine:
         # step > admit, decode.prep, decode/chunk{n}, decode.retire; the
         # device calls prefill/* (category "prefill") and decode/*
         # ("decode") with their dispatch and sync, each with the
-        # `state_bytes` of recurrent state its lanes hold; the rest
-        # category "engine". Without such a tracer no span is made and no
+        # `state_bytes` of recurrent state its lanes hold, a prefill also
+        # with the `width` in lanes it computed; the rest category
+        # "engine". Without such a tracer no span is made and no
         # annotation opened.
         self.tracer = tracer
         # optional obs.metrics.MetricsRegistry. Recording is host-side
@@ -442,12 +454,13 @@ class ServeEngine:
         return grew
 
     def _observe_prefill(self, path: str, tokens: int, lanes: int,
-                        seconds: float) -> None:
+                         width: int, seconds: float) -> None:
         m = self.metrics
         if m is None:
             return
         m.counter("serve.prefill.calls", path=path).inc()
         m.counter("serve.prefill.tokens").inc(tokens)
+        m.counter("serve.prefill.pad_lanes").inc(width - lanes)
         m.counter("serve.prefill.seconds").inc(seconds)
         m.histogram("serve.prefill.us").record(seconds * 1e6)
         m.gauge("serve.prefill.lanes").set(lanes)
@@ -488,6 +501,12 @@ class ServeEngine:
         b = max(self.min_bucket, prompt_len)
         b = 1 << (b - 1).bit_length()                # next power of two
         return min(b, self.max_len)
+
+    def _width(self, bucket: int) -> int:
+        """Lanes of a bucket-`bucket` prefill call (see PREFILL_ROWS): one
+        width per bucket, so still one program per bucket."""
+        w = -(-PREFILL_ROWS // bucket)
+        return min(self.slots, 1 << (w - 1).bit_length())
 
     def _admit(self) -> None:
         with self._span("admit"):
@@ -532,7 +551,11 @@ class ServeEngine:
                     # head bucket blocked on pages this quantum; retires at
                     # the next chunk sync will free some
                     return
-                self._prefill_group(take, free[: len(take)], b)
+                # one call per W(b) of the group, each with its own fault
+                # handling: a failed call sheds only its own requests
+                w = self._width(b)
+                for i in range(0, len(take), w):
+                    self._prefill_group(take[i: i + w], free[i: i + w], b)
 
     # -- bucketed prefill ------------------------------------------------
     def _probe_batch_axes(self):
@@ -562,10 +585,11 @@ class ServeEngine:
 
     def _prefill_group(self, reqs: list[Request], slot_list: list[int],
                        bucket: int) -> None:
+        width = self._width(bucket)
         with self._span("prefill.pack"):
-            toks = np.zeros((self.slots, bucket), np.int32)
-            true_lens = np.ones(self.slots, np.int32)  # pad lanes: len 1
-            slot_ids = np.full(self.slots, -1, np.int32)
+            toks = np.zeros((width, bucket), np.int32)
+            true_lens = np.ones(width, np.int32)       # pad lanes: len 1
+            slot_ids = np.full(width, -1, np.int32)
             for g, (r, s) in enumerate(zip(reqs, slot_list)):
                 S = len(r.prompt)
                 toks[g, :S] = r.prompt
@@ -579,7 +603,7 @@ class ServeEngine:
                 # sentinel-padded) for the page-granular scatter. The
                 # slot-indexed device page_table is pushed separately
                 # before the next decode chunk (step() checks pool.dirty).
-                dest = np.full((self.slots, self._pool.pages_per_lane),
+                dest = np.full((width, self._pool.pages_per_lane),
                                self._pool.sentinel, np.int32)
                 for g, (r, s) in enumerate(zip(reqs, slot_list)):
                     self._pool.map_to(s, len(r.prompt))
@@ -616,7 +640,8 @@ class ServeEngine:
             if sp is not None:
                 # the group's own lanes are activated only below
                 sp.done(t_start, t_end, bucket=bucket, lanes=len(reqs),
-                        tokens=n_tokens, rids=[r.rid for r in reqs],
+                        width=width, tokens=n_tokens,
+                        rids=[r.rid for r in reqs],
                         stalled_lanes=sum(r is not None
                                           for r in self.active),
                         compiled=compiled,
@@ -625,7 +650,7 @@ class ServeEngine:
             for r in reqs:       # successful work only enters the trace
                 self.tracer.on_prefill(r.rid, len(r.prompt),
                                        t=t_start - self._t0)
-        self._observe_prefill("bucketed", n_tokens, len(reqs),
+        self._observe_prefill("bucketed", n_tokens, len(reqs), width,
                               t_end - t_start)
         # a lane whose prefill logits were non-finite is encoded as a -1
         # first token (impl below) — shed it before the slot is activated
@@ -679,7 +704,7 @@ class ServeEngine:
         With the guard on, the forward runs under a GuardTape (every pod
         GEMM verified; `sdc` is the traced injection plan) and the tape
         totals become an extra output riding the existing sync."""
-        lane_cache = self.model.init_cache(self.slots, self.max_len,
+        lane_cache = self.model.init_cache(tokens.shape[0], self.max_len,
                                            src_len=self.src_len)
         # true_lens drives the stateful families' masked state updates
         # (SSM dt-masking + conv window, ring slot gather); attention-only
@@ -705,14 +730,14 @@ class ServeEngine:
 
     def _prefill_batched_impl(self, params, tokens, big_cache, slot_ids,
                               true_lens, sdc=None):
-        """One jitted prefill over a fixed [slots, bucket] token batch:
+        """One jitted prefill over a [W(bucket), bucket] token batch:
         forward (see _prefill_forward) then scatter of each real lane into
-        its slot of the batched cache. Compiles once per bucket (tokens'
-        trailing dim is the only varying shape)."""
+        its slot of the batched cache. Every size comes from the tokens'
+        shape, which the bucket fixes, so it compiles once per bucket."""
         first_tok, lane_cache, gstats = self._prefill_forward(
             params, tokens, true_lens, sdc)
         cache = big_cache
-        for g in range(self.slots):                   # static unroll
+        for g in range(tokens.shape[0]):              # static unroll
             valid = slot_ids[g] >= 0
             slot = jnp.maximum(slot_ids[g], 0)
             cache = jax.tree.map(
@@ -740,7 +765,7 @@ class ServeEngine:
         first_tok, lane_cache, gstats = self._prefill_forward(
             params, tokens, true_lens, sdc)
         cache = _paged_insert(big_cache, lane_cache, self._batch_axes,
-                              slot_ids, true_lens, dest_pages, self.slots)
+                              slot_ids, true_lens, dest_pages)
         if self._guard_on:
             return first_tok, cache, gstats
         return first_tok, cache
@@ -782,8 +807,8 @@ class ServeEngine:
             t_end = self._clock()
             compiled = self._compiled("prefill", new_len)
             if sp is not None:
-                sp.done(t_start, t_end, bucket=S, lanes=1, tokens=S,
-                        rids=[req.rid],
+                sp.done(t_start, t_end, bucket=S, lanes=1, width=1,
+                        tokens=S, rids=[req.rid],
                         stalled_lanes=sum(r is not None
                                           for r in self.active),
                         compiled=compiled,
@@ -793,7 +818,7 @@ class ServeEngine:
             return
         if self.tracer is not None:
             self.tracer.on_prefill(req.rid, S, t=t_start - self._t0)
-        self._observe_prefill("exact", S, 1, t_end - t_start)
+        self._observe_prefill("exact", S, 1, 1, t_end - t_start)
         self.active[slot] = req
         self.positions[slot] = S
         self.budgets[slot] = self.admission.clamp_budget(
@@ -1248,7 +1273,7 @@ _CACHE_NODES = (KVCache, PagedKVCache, RingKVCache, MLACache, SSMCache,
 
 
 def _paged_insert(big_cache, lane_cache, batch_axes, slot_ids, true_lens,
-                  dest_pages, slots: int):
+                  dest_pages):
     """Merge a dense transient prefill cache into the persistent paged
     cache, node by node: PagedKVCache nodes take the page-granular scatter
     (their dense twin in `lane_cache` reshapes to pages and lands on the
@@ -1266,7 +1291,7 @@ def _paged_insert(big_cache, lane_cache, batch_axes, slot_ids, true_lens,
 
         def one(b, l, a):
             out = b
-            for g in range(slots):                    # static unroll
+            for g in range(slot_ids.shape[0]):        # static unroll
                 valid = slot_ids[g] >= 0
                 s = jnp.maximum(slot_ids[g], 0)
                 out = jnp.where(

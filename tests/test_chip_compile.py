@@ -226,7 +226,9 @@ def test_mamba2_served_programs_fit_one_v5e(one_chip, monkeypatch):
     a [64, 512] bucket compiled for one chip, each within its 16 GB with
     the parameters and the cache it takes (bytes recorded in
     bench/configs/mamba2-370m.json's notes), and in_proj, out_proj and
-    the tied head on the pod GEMM in each."""
+    the tied head on the pod GEMM in each. The prefill the engine runs at
+    bucket 512 is [W(512), 512] = [1, 512] (the width comes from the
+    tokens' shape, so [64, 512] still lowers), and it needs fewer bytes."""
     import repro.kernels.systolic_gemm.ops as ops
     from repro.configs import get_arch
     from repro.models.model import Model
@@ -253,6 +255,11 @@ def test_mamba2_served_programs_fit_one_v5e(one_chip, monkeypatch):
         engine._prefill_fn.lower(params, i32(64, 512), cache, i32(64),
                                  i32(64)),
     ]
+    w = engine._width(512)
+    assert w == 1
+    progs.append(engine._prefill_fn.lower(params, i32(w, 512), cache,
+                                          i32(w), i32(w)))
+    needs = []
     for low in progs:
         compiled = low.compile()
         m = compiled.memory_analysis()
@@ -260,4 +267,6 @@ def test_mamba2_served_programs_fit_one_v5e(one_chip, monkeypatch):
                 + m.output_size_in_bytes - m.alias_size_in_bytes)
         assert need < 16 * 10 ** 9, need
         assert compiled.as_text().count("tpu_custom_call") == 3
+        needs.append(need)
+    assert needs[2] < needs[1], needs
     jax.clear_caches()

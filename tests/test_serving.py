@@ -152,6 +152,51 @@ def test_requests_with_extras_skip_the_bucket_batch():
 
 
 # --------------------------------------------------------------------------
+# prefill width: a bucket-b call computes W(b) lanes, not every slot
+# --------------------------------------------------------------------------
+
+@pytest.mark.parametrize("arch", ["granite-8b", "mamba2-370m"])
+@pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
+@pytest.mark.parametrize("lengths,width,calls", [
+    ((70, 90, 120), 2, [2, 1]),    # bucket 128: W = 2 < 4 slots, split
+    ((9, 12, 15), 4, [3]),         # bucket 16: W = slots, one call
+], ids=["split", "whole"])
+def test_prefill_group_splits_by_bucket_width(arch, paged, lengths, width,
+                                              calls):
+    """A same-bucket group of k requests is prefilled in ceil(k / W(b))
+    calls of width W(b) = min(slots, next_pow2(ceil(256 / b))), whose
+    spans carry `width` and `lanes` summing to k; the served tokens equal
+    a one-request-at-a-time run's, one program per bucket, and
+    serve.prefill.pad_lanes counts the computed lanes that held no
+    request."""
+    from repro.obs.metrics import MetricsRegistry
+    from repro.tenancy.trace import ServeTraceRecorder
+    cfg, model, params = _setup(arch)
+    rng = np.random.default_rng(7)
+    prompts = [rng.integers(0, cfg.vocab, n, dtype=np.int32)
+               for n in lengths]
+    kw = dict(slots=4, max_len=256, decode_chunk=4, paged=paged)
+    rec, reg = ServeTraceRecorder(), MetricsRegistry()
+    eng, grouped = _run(ServeEngine, model, params, prompts, max_new=6,
+                        tracer=rec, metrics=reg, **kw)
+    assert eng.bucketed
+    pre = [s for s in rec.spans if s.cat == "prefill"]
+    assert [s.args["lanes"] for s in pre] == calls
+    assert all(s.args["width"] == width == eng._width(s.args["bucket"])
+               for s in pre)
+    assert reg.value("serve.prefill.pad_lanes") == \
+        width * len(calls) - len(lengths)
+    assert eng.prefill_compiles == len(eng._buckets_seen) == 1
+
+    alone = ServeEngine(model, params, **kw)
+    for i, p in enumerate(prompts):
+        r = Request(rid=i, prompt=p, max_new_tokens=6)
+        alone.submit(r)
+        alone.run_to_completion(max_steps=100)
+        assert r.done and r.out == grouped[i], i
+
+
+# --------------------------------------------------------------------------
 # jit compile-count regression (the bounded-bucket guarantee)
 # --------------------------------------------------------------------------
 
